@@ -46,8 +46,8 @@ def paged_decode_sweep() -> tuple[list[dict], dict]:
     q = jnp.asarray(rng.normal(size=(B, HKV * G, D)).astype(np.float32))
     k_new = jnp.asarray(rng.normal(size=(B, HKV, D)).astype(np.float32))
     v_new = jnp.asarray(rng.normal(size=(B, HKV, D)).astype(np.float32))
-    k_pages = jnp.asarray(rng.normal(size=(NP, PS, HKV, D)).astype(np.float32))
-    v_pages = jnp.asarray(rng.normal(size=(NP, PS, HKV, D)).astype(np.float32))
+    k_pages = jnp.asarray(rng.normal(size=(NP, HKV, PS, D)).astype(np.float32))
+    v_pages = jnp.asarray(rng.normal(size=(NP, HKV, PS, D)).astype(np.float32))
     lengths_np = rng.integers(1, LP * PS + 1, size=B)
     n_mapped = -(-lengths_np // PS)                  # pages actually in use
     frames = rng.permutation(NP)[:B * LP].reshape(B, LP)

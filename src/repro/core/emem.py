@@ -31,7 +31,6 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PSpec
 
 
@@ -205,6 +204,19 @@ def _mem_pspec(axes: Sequence[str]) -> PSpec:
     return PSpec(tuple(axes) if len(axes) > 1 else axes[0])
 
 
+@functools.lru_cache(maxsize=None)
+def _sharded(shard_fn, spec: EMemSpec, mesh: Mesh, axes: tuple[str, ...],
+             capacity: int, n_args: int):
+    """``shard_fn`` over the memory shards, jitted once per geometry.  A
+    bare ``shard_map`` called outside a jit runs its body op by op and
+    compiles every op again on each call."""
+    body = functools.partial(shard_fn, spec, axes, capacity=capacity)
+    mem_ps = _mem_pspec(axes)
+    return jax.jit(jax.shard_map(body, mesh=mesh,
+                                 in_specs=(mem_ps,) * n_args,
+                                 out_specs=mem_ps, check_vma=False))
+
+
 def read(spec: EMemSpec, mesh: Mesh, axes: Sequence[str], data: jax.Array,
          addrs: jax.Array, capacity_factor: float = 2.0) -> jax.Array:
     """Distributed random read of ``addrs`` (global [R]) -> [R, width]."""
@@ -213,13 +225,7 @@ def read(spec: EMemSpec, mesh: Mesh, axes: Sequence[str], data: jax.Array,
     assert n_shards == spec.n_shards, (n_shards, spec.n_shards)
     r_shard = addrs.shape[0] // n_shards
     cap = capacity_for(spec, r_shard, capacity_factor)
-    body = functools.partial(read_shard, spec, axes, capacity=cap)
-    mem_ps = _mem_pspec(axes)
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(mem_ps, mem_ps),
-                   out_specs=mem_ps,
-                   check_rep=False)
-    return fn(data, addrs)
+    return _sharded(read_shard, spec, mesh, axes, cap, 2)(data, addrs)
 
 
 def write(spec: EMemSpec, mesh: Mesh, axes: Sequence[str], data: jax.Array,
@@ -231,13 +237,8 @@ def write(spec: EMemSpec, mesh: Mesh, axes: Sequence[str], data: jax.Array,
     assert n_shards == spec.n_shards
     r_shard = addrs.shape[0] // n_shards
     cap = capacity_for(spec, r_shard, capacity_factor)
-    body = functools.partial(write_shard, spec, axes, capacity=cap)
-    mem_ps = _mem_pspec(axes)
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(mem_ps, mem_ps, mem_ps),
-                   out_specs=mem_ps,
-                   check_rep=False)
-    return fn(data, addrs, values)
+    return _sharded(write_shard, spec, mesh, axes, cap, 3)(data, addrs,
+                                                           values)
 
 
 def sharding_for(spec: EMemSpec, mesh: Mesh, axes: Sequence[str]) -> NamedSharding:

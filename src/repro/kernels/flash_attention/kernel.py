@@ -17,11 +17,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pragma: no cover - version dependent
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -115,13 +111,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         pltpu.VMEM((block_q, 1), jnp.float32),   # l
         pltpu.VMEM((block_q, d), jnp.float32),   # acc
     ]
-    params = {}
-    cp = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None)
-    if cp is not None:
-        params["compiler_params"] = cp(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-
     out = pl.pallas_call(
         kernel,
         grid=(b * hq, sq // block_q, skv // block_k),
@@ -134,6 +123,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((b * hq, sq, d), q.dtype),
         scratch_shapes=scratch,
         interpret=interpret,
-        **params,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(qf, kf, vf)
     return out.reshape(b, hq, sq, d)

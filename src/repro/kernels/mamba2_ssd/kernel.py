@@ -18,13 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pragma: no cover
-    from jax.experimental.pallas import tpu as pltpu
-    PrefetchScalarGridSpec = pltpu.PrefetchScalarGridSpec
-except Exception:  # pragma: no cover
-    pltpu = None
-    PrefetchScalarGridSpec = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _ssd_kernel(a_ref, d_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, state_sc, *,
@@ -92,7 +86,7 @@ def ssd(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
         batch, head = bh // h, bh % h
         return (batch * g + head // heads_per_group, c, 0)
 
-    grid_spec = PrefetchScalarGridSpec(
+    grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(bt * h, s // chunk),
         in_specs=[

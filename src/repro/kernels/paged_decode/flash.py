@@ -20,13 +20,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pragma: no cover
-    from jax.experimental.pallas import tpu as pltpu
-    PrefetchScalarGridSpec = pltpu.PrefetchScalarGridSpec
-except Exception:  # pragma: no cover
-    pltpu = None
-    PrefetchScalarGridSpec = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -96,7 +90,7 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
     block_k = min(block_k, s)
     assert s % block_k == 0
 
-    grid_spec = PrefetchScalarGridSpec(
+    grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(b, hkv, s // block_k),
         in_specs=[

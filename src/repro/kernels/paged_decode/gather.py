@@ -20,13 +20,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-# pltpu.PrefetchScalarGridSpec moved between jax versions; resolve lazily.
-try:  # pragma: no cover - version dependent
-    from jax.experimental.pallas import tpu as pltpu
-    PrefetchScalarGridSpec = pltpu.PrefetchScalarGridSpec
-except Exception:  # pragma: no cover
-    PrefetchScalarGridSpec = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _gather_slots_kernel(slots_ref, page_ref, out_ref, *, page_slots: int):
@@ -54,7 +48,7 @@ def gather_slots(pages: jax.Array, slots: jax.Array, *,
         page = jnp.where(slot >= 0, slot // page_slots, 0)
         return (page, 0, 0)
 
-    grid_spec = PrefetchScalarGridSpec(
+    grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(q,),
         in_specs=[pl.BlockSpec((1, page_slots, width), page_index_map)],
@@ -91,7 +85,7 @@ def gather_pages(pages: jax.Array, page_ids: jax.Array, *,
         pid = ids_ref[pi]
         return (jnp.where(pid >= 0, pid, 0), 0, 0)
 
-    grid_spec = PrefetchScalarGridSpec(
+    grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(p,),
         in_specs=[pl.BlockSpec((1, page_slots, width), page_index_map)],

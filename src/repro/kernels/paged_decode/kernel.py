@@ -35,6 +35,10 @@ Two kernels, mirroring the WRITE / READ halves of the paper's protocol:
     (acc, m, l) so the sequence-parallel dispatch layer can log-sum-exp
     merge partials across KV shards, identically to the composed path.
 
+Pages are laid out ``[n_pages, Hkv, slots, D]``: one KV head of one page
+is a contiguous ``[slots, D]`` slab, so the read kernel's block has legal
+TPU trailing dimensions, and the write kernel moves whole pages.
+
 Both take a ``meta`` scalar operand ``[sid, n_shards, kv_start]`` so one
 compiled kernel serves every shard of a shard_map body (sid/kv_start are
 traced axis indices).  Interpret mode keeps tier-1 running on CPU.
@@ -46,15 +50,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pragma: no cover
-    from jax.experimental.pallas import tpu as pltpu
-    PrefetchScalarGridSpec = pltpu.PrefetchScalarGridSpec
-except Exception:  # pragma: no cover
-    pltpu = None
-    PrefetchScalarGridSpec = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+#: attention matmuls run at full float32 precision, as in the composed
+#: oracle (``ref.py``), so the two impls agree to float32 rounding on TPU
+#: too, where the default precision rounds operands to bf16
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 # -- WRITE: scatter the new K/V token row into its owning frame ---------------
@@ -94,14 +96,15 @@ def _kv_write_kernel(bt_ref, len_ref, fr_ref, wm_ref, meta_ref,
 
     writer, off = jax.lax.fori_loop(0, n_seqs, scan,
                                     (jnp.int32(-1), jnp.int32(0)))
-    k_out_ref[...] = k_in_ref[...]
-    v_out_ref[...] = v_in_ref[...]
-
-    @pl.when(writer >= 0)
-    def _write():
-        w = jnp.where(writer >= 0, writer, 0)
-        k_out_ref[0, off] = k_new_ref[w].astype(k_out_ref.dtype)
-        v_out_ref[0, off] = v_new_ref[w].astype(v_out_ref.dtype)
+    # Mosaic cannot store at a dynamic slot (sublane) offset, so the row
+    # lands by a select over the whole [Hkv, slots, D] page
+    hit = ((jax.lax.broadcasted_iota(jnp.int32, k_in_ref.shape[1:], 1)
+            == off) & (writer >= 0))
+    w = jnp.where(writer >= 0, writer, 0)
+    k_out_ref[0] = jnp.where(
+        hit, k_new_ref[w].astype(k_out_ref.dtype)[:, None, :], k_in_ref[0])
+    v_out_ref[0] = jnp.where(
+        hit, v_new_ref[w].astype(v_out_ref.dtype)[:, None, :], v_in_ref[0])
 
 
 def paged_kv_write(k_new: jax.Array, v_new: jax.Array, k_pages: jax.Array,
@@ -109,12 +112,12 @@ def paged_kv_write(k_new: jax.Array, v_new: jax.Array, k_pages: jax.Array,
                    lengths: jax.Array, frame_ro: jax.Array,
                    write_mask: jax.Array, meta: jax.Array, *,
                    interpret: bool = False):
-    """k_new/v_new: [B, Hkv, D]; k/v_pages: [np_loc, slots, Hkv, D] (this
+    """k_new/v_new: [B, Hkv, D]; k/v_pages: [np_loc, Hkv, slots, D] (this
     shard's pages); block_table: [B, max_lpages] GLOBAL frame ids;
     meta: [sid, n_shards, kv_start].  Returns updated (k_pages, v_pages),
     HBM-aliased with the inputs."""
     b, hkv, d = k_new.shape
-    np_loc, page_slots = k_pages.shape[0], k_pages.shape[1]
+    np_loc, page_slots = k_pages.shape[0], k_pages.shape[2]
     max_lpages = block_table.shape[1]
 
     def page_map(bb, bt_ref, len_ref, fr_ref, wm_ref, meta_ref):
@@ -123,18 +126,18 @@ def paged_kv_write(k_new: jax.Array, v_new: jax.Array, k_pages: jax.Array,
                                 max_lpages=max_lpages, np_loc=np_loc)
         return (row, 0, 0, 0)
 
-    grid_spec = PrefetchScalarGridSpec(
+    grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(b,),
         in_specs=[
             pl.BlockSpec((b, hkv, d), lambda bb, *_: (0, 0, 0)),
             pl.BlockSpec((b, hkv, d), lambda bb, *_: (0, 0, 0)),
-            pl.BlockSpec((1, page_slots, hkv, d), page_map),
-            pl.BlockSpec((1, page_slots, hkv, d), page_map),
+            pl.BlockSpec((1, hkv, page_slots, d), page_map),
+            pl.BlockSpec((1, hkv, page_slots, d), page_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, page_slots, hkv, d), page_map),
-            pl.BlockSpec((1, page_slots, hkv, d), page_map),
+            pl.BlockSpec((1, hkv, page_slots, d), page_map),
+            pl.BlockSpec((1, hkv, page_slots, d), page_map),
         ],
     )
     kernel = functools.partial(_kv_write_kernel, page_slots=page_slots,
@@ -181,9 +184,10 @@ def _gather_attend_kernel(bt_ref, len_ref, meta_ref, q_ref, k_ref, v_ref,
     @pl.when(run)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)                  # [G, D]
-        k = k_ref[0, :, 0].astype(jnp.float32)               # [PS, D]
-        v = v_ref[0, :, 0].astype(jnp.float32)               # [PS, D]
+        k = k_ref[0, 0].astype(jnp.float32)                  # [PS, D]
+        v = v_ref[0, 0].astype(jnp.float32)                  # [PS, D]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                precision=HIGHEST,
                                 preferred_element_type=jnp.float32) * scale
         pos = j * page_slots + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         valid = pos < length
@@ -197,7 +201,7 @@ def _gather_attend_kernel(bt_ref, len_ref, meta_ref, q_ref, k_ref, v_ref,
         p = jnp.where(valid, p, 0.0)
         l_sc[...] = l_sc[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
         acc_sc[...] = acc_sc[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            p, v, (((1,), (0,)), ((), ())), precision=HIGHEST,
             preferred_element_type=jnp.float32)
         m_sc[...] = m_new
 
@@ -213,12 +217,16 @@ def paged_gather_attend(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                         meta: jax.Array, *, scale: float | None = None,
                         window: int | None = None, interpret: bool = False):
     """q: [B, Hkv_loc, G, D] (this tp shard's query-head groups);
-    k/v_pages: [np_loc, slots, Hkv, D]; block_table: [B, max_lpages] GLOBAL
+    k/v_pages: [np_loc, Hkv, slots, D]; block_table: [B, max_lpages] GLOBAL
     frame ids; meta: [sid, n_shards, kv_start] with kv_start the first KV
     head of this tp shard.  Returns UNNORMALIZED partials
-    (acc [B, Hkv_loc, G, D] f32, m, l [B, Hkv_loc, G, 1] f32)."""
+    (acc [B, Hkv_loc, G, D] f32, m, l [B, Hkv_loc, G, 1] f32).
+
+    Each grid cell streams one head's ``[slots, D]`` slab of one page, the
+    tile-aligned trailing block a TPU BlockSpec accepts (``slots`` a
+    multiple of 8, ``D`` of 128, or the whole axis)."""
     b, hkv_loc, g, d = q.shape
-    np_loc, page_slots = k_pages.shape[0], k_pages.shape[1]
+    np_loc, page_slots = k_pages.shape[0], k_pages.shape[2]
     max_lpages = block_table.shape[1]
     scale = (d ** -0.5) if scale is None else scale
 
@@ -227,15 +235,15 @@ def paged_gather_attend(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         ns = meta_ref[1]
         ok = (f >= 0) & (f % ns == meta_ref[0])
         row = jnp.clip(jnp.where(ok, f // ns, 0), 0, np_loc - 1)
-        return (row, 0, meta_ref[2] + h, 0)
+        return (row, meta_ref[2] + h, 0, 0)
 
-    grid_spec = PrefetchScalarGridSpec(
+    grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b, hkv_loc, max_lpages),
         in_specs=[
             pl.BlockSpec((1, 1, g, d), lambda bb, h, j, *_: (bb, h, 0, 0)),
-            pl.BlockSpec((1, page_slots, 1, d), row_map),
-            pl.BlockSpec((1, page_slots, 1, d), row_map),
+            pl.BlockSpec((1, 1, page_slots, d), row_map),
+            pl.BlockSpec((1, 1, page_slots, d), row_map),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, g, d), lambda bb, h, j, *_: (bb, h, 0, 0)),

@@ -13,8 +13,8 @@ shard owns -- so the caller's log-sum-exp merge is impl-independent:
 ``fused``      the VM-walking Pallas kernels
                (`repro.kernels.paged_decode.kernel`), interpret-mode off
                TPU.  Requires whole KV-head groups per tp shard
-               (``hl % group == 0``); :func:`resolve_impl` falls back to
-               ``composed`` otherwise.
+               (``hl % group == 0``); :func:`resolve_impl` refuses an
+               explicit ``fused`` otherwise.
 
 Without VM tables (batch ``kv_layout``; ``use_vm=False``) the fused path
 synthesizes the identity block table in-jit -- sequence ``b`` owns frames
@@ -35,13 +35,21 @@ def _on_tpu() -> bool:
 
 
 def resolve_impl(paged_kernel: str, hl: int, group: int) -> str:
-    """Map the ModelConfig ``paged_kernel`` flag + platform to an impl."""
-    fused_ok = (hl % group == 0) and _k.PrefetchScalarGridSpec is not None
-    if paged_kernel == "composed" or not fused_ok:
-        return "composed"
-    if paged_kernel == "fused":
-        return "fused"
-    return "fused" if _on_tpu() else "composed"     # "auto"
+    """Map the ModelConfig ``paged_kernel`` flag + platform to an impl.
+
+    ``"auto"`` is ``fused`` on TPU when the ``hl`` local query heads split
+    into whole KV-head groups, else ``composed``.  An explicit ``"fused"``
+    that cannot run is a ValueError, never a quiet switch to the oracle."""
+    if paged_kernel not in ("auto", "fused", "composed"):
+        raise ValueError(f"unknown paged_kernel {paged_kernel!r}")
+    whole_groups = hl % group == 0
+    if paged_kernel == "fused" and not whole_groups:
+        raise ValueError(
+            f"paged_kernel='fused' needs whole KV-head groups per tensor-"
+            f"parallel shard: {hl} local heads, groups of {group}")
+    if paged_kernel == "auto":
+        return "fused" if _on_tpu() and whole_groups else "composed"
+    return paged_kernel
 
 
 def _identity_tables(b: int, max_pages: int):
@@ -58,7 +66,7 @@ def paged_decode_shard(q, k_new, v_new, k_pages, v_pages, lengths, bt, fl,
     """One shard of the paged decode step.
 
     q: [B, Hl, hd] local query heads (whole KV-head groups for ``fused``);
-    k_new/v_new: [B, Hkv, hd]; k/v_pages: [np_loc, slots, Hkv, hd] local;
+    k_new/v_new: [B, Hkv, hd]; k/v_pages: [np_loc, Hkv, slots, hd] local;
     bt/fl/fr: replicated VM tables (ignored when ``use_vm`` is False);
     wm: [B] write mask; sid/head_start may be traced axis indices.
     Returns (acc [B, Hl, hd] f32 unnormalized, m [B, Hl], l [B, Hl],

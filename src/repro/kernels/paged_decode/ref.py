@@ -51,13 +51,13 @@ def partial_attend(q, k_pages, v_pages, lengths, *, owner, lpage,
                    head_start, group, window):
     """Partial attention of q against this shard's pages.
 
-    q: [B, Hl, hd] (local heads); k/v_pages: [np_loc, slots, Hkv, hd];
+    q: [B, Hl, hd] (local heads); k/v_pages: [np_loc, Hkv, slots, hd];
     owner: [B, np_loc] -- whether each local page belongs to sequence b
     (several rows may claim one page under prefix sharing); lpage: [np_loc]
     logical in-sequence page of each local page.
     Returns (acc [B, Hl, hd] unnormalized, m [B, Hl], l [B, Hl])."""
     b, hl, hd = q.shape
-    np_loc, slots, hkv, _ = k_pages.shape
+    np_loc, hkv, slots, _ = k_pages.shape
     scale = hd ** -0.5
 
     # in-sequence position of each local token, and who may attend it
@@ -68,12 +68,15 @@ def partial_attend(q, k_pages, v_pages, lengths, *, owner, lpage,
 
     # per-local-head KV head selection
     kvh = (head_start + jnp.arange(hl)) // group           # [Hl]
-    kf = k_pages.reshape(np_loc * slots, hkv, hd).astype(jnp.float32)
-    vf = v_pages.reshape(np_loc * slots, hkv, hd).astype(jnp.float32)
+    kf = k_pages.transpose(0, 2, 1, 3).reshape(np_loc * slots, hkv, hd)
+    vf = v_pages.transpose(0, 2, 1, 3).reshape(np_loc * slots, hkv, hd)
+    kf, vf = kf.astype(jnp.float32), vf.astype(jnp.float32)
     k_sel = jnp.take(kf, kvh, axis=1)                      # [T_loc, Hl, hd]
     v_sel = jnp.take(vf, kvh, axis=1)
 
-    logits = jnp.einsum("bhd,thd->bht", q.astype(jnp.float32), k_sel) * scale
+    hi = jax.lax.Precision.HIGHEST         # as the fused kernel's dots
+    logits = jnp.einsum("bhd,thd->bht", q.astype(jnp.float32), k_sel,
+                        precision=hi) * scale
     valid = tok_owned & (tok_pos[None, :] < lengths[:, None])  # [B, T_loc]
     if window is not None:
         valid &= tok_pos[None, :] >= (lengths[:, None] - window)
@@ -82,7 +85,7 @@ def partial_attend(q, k_pages, v_pages, lengths, *, owner, lpage,
     p = jnp.exp(logits - m[..., None])
     p = jnp.where(valid[:, None, :], p, 0.0)
     l = p.sum(-1)
-    acc = jnp.einsum("bht,thd->bhd", p, v_sel)
+    acc = jnp.einsum("bht,thd->bhd", p, v_sel, precision=hi)
     return acc, m, l
 
 
@@ -94,7 +97,7 @@ def paged_decode_shard(q, k_new, v_new, k_pages, v_pages, lengths, bt, fl,
     ``ops.paged_decode_shard``: returns (acc, m, l, k_pages, v_pages) with
     ``acc`` unnormalized so the caller can merge across shards."""
     b = q.shape[0]
-    np_loc, slots = k_pages.shape[0], k_pages.shape[1]
+    np_loc, slots = k_pages.shape[0], k_pages.shape[2]
     bt_ = bt if use_vm else None
     fl_ = fl if use_vm else None
     # WRITE: scatter the new K/V row into its owning shard's page
@@ -103,10 +106,10 @@ def paged_decode_shard(q, k_new, v_new, k_pages, v_pages, lengths, bt, fl,
     rows = jnp.where(ok & (gpage % n_shards == sid),
                      gpage // n_shards, np_loc)
     off = (lengths - 1) % slots
-    k_pages = k_pages.at[rows, off].set(k_new.astype(k_pages.dtype),
-                                        mode="drop")
-    v_pages = v_pages.at[rows, off].set(v_new.astype(v_pages.dtype),
-                                        mode="drop")
+    k_pages = k_pages.at[rows, :, off].set(k_new.astype(k_pages.dtype),
+                                           mode="drop")
+    v_pages = v_pages.at[rows, :, off].set(v_new.astype(v_pages.dtype),
+                                           mode="drop")
     # READ/compute: partial attention over owned pages
     g_all = shard_frames(jnp.arange(np_loc), sid, n_shards)  # global frames
     owner, lpage = owner_mask(bt_, fl_, g_all, b, max_pages)

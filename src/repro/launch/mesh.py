@@ -8,17 +8,7 @@ import; smoke tests and benches see the real single device.
 from __future__ import annotations
 
 import jax
-
-
-def compat_make_mesh(shape, axes):
-    """``jax.make_mesh`` across jax versions: ``axis_types`` (and
-    ``jax.sharding.AxisType``) only exist on newer releases; older ones
-    default every axis to Auto anyway, which is what we want."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -26,7 +16,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     axis.  The multi-pod dry-run proves the "pod" axis shards."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def dp_axes(multi_pod: bool) -> tuple[str, ...]:
@@ -37,4 +27,5 @@ def make_host_mesh(n_devices: int | None = None, tp: int = 1):
     """Small mesh over whatever devices exist (tests, examples)."""
     n = n_devices or len(jax.devices())
     assert n % tp == 0
-    return compat_make_mesh((n // tp, tp), ("data", "model"))
+    return jax.make_mesh((n // tp, tp), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

@@ -3,6 +3,13 @@
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-0.6b --smoke \
       --requests 6 --prompt-len 12 --max-new 16
 
+``--kv-layout pooled`` serves from the emulated-memory page pool (frames
+allocated on demand, prefix sharing, swap); ``--kv-shards N`` spreads that
+pool over N devices, each holding 1/N of the frames from the start:
+
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen3-0.6b \
+      --kv-layout pooled --page-slots 256 --slots 8 --max-len 2048
+
 ``--trace`` switches from submit-everything-up-front to a seeded synthetic
 trace (Poisson arrivals, Zipf prompt popularity, bimodal lengths) replayed
 against the engine's decode-step clock, so requests genuinely queue; the
@@ -11,10 +18,14 @@ inter-token latency, queue wait -- all in decode steps):
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-0.6b --smoke \
       --trace --requests 24 --arrival-rate 0.3 --zipf-alpha 1.2
+
+:func:`main` takes the same arguments as a list and returns the report the
+command line prints, so a script can serve in-process.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import time
 
@@ -22,13 +33,16 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config, list_archs
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_host_mesh
 from repro.models.model import Model
+from repro.parallel import mesh_ctx
 from repro.serve.engine import EngineConfig, Request, ServeEngine
 from repro.serve.scheduler import Scheduler, SchedulerConfig
 from repro.serve.tracegen import TraceConfig, generate, replay
 
 
-def main() -> None:
+def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list_archs(), default="qwen3-0.6b")
     ap.add_argument("--smoke", action="store_true")
@@ -37,8 +51,21 @@ def main() -> None:
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-len", type=int, default=128)
-    ap.add_argument("--paged", action="store_true",
-                    help="use the emulated-memory paged KV layout")
+    ap.add_argument("--kv-layout", choices=("batch", "paged", "pooled"),
+                    default="batch",
+                    help="KV store: per-slot arrays, or the emulated-memory "
+                         "page pool with reserved (paged) or on-demand "
+                         "(pooled) frames")
+    ap.add_argument("--page-slots", type=int, default=None,
+                    help="token positions per KV page (default: the "
+                         "config's kv_page_slots)")
+    ap.add_argument("--paged-kernel", choices=("auto", "fused", "composed"),
+                    default="auto",
+                    help="paged-decode impl: the fused Pallas kernels, the "
+                         "composed jnp oracle, or auto (fused on TPU)")
+    ap.add_argument("--kv-shards", type=int, default=1,
+                    help="devices the KV page pool is spread over (the "
+                         "'data' axis of a host mesh); 1 keeps it on one")
     ap.add_argument("--max-fused-steps", type=int, default=8,
                     help="decode steps fused into one jitted while-loop "
                          "run between control-plane events; 1 reproduces "
@@ -84,15 +111,26 @@ def main() -> None:
     ap.add_argument("--zipf-alpha", type=float, default=1.2,
                     help="prompt-popularity skew (larger = hotter head)")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def build(args: argparse.Namespace) -> tuple[Model, dict, ServeEngine]:
+    """Model, params (from ``--seed``) and engine for parsed arguments.
+    Installs the KV mesh context for ``--kv-shards`` > 1 and clears any
+    installed one otherwise, so the engine's page pool is born where the
+    arguments say."""
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    if args.paged:
-        import dataclasses
-        cfg = dataclasses.replace(cfg, kv_layout="paged", kv_page_slots=16)
+    cfg = dataclasses.replace(cfg, kv_layout=args.kv_layout,
+                              paged_kernel=args.paged_kernel)
+    if args.page_slots is not None:
+        cfg = dataclasses.replace(cfg, kv_page_slots=args.page_slots)
+    if args.kv_shards > 1:
+        mesh_ctx.set_context(make_host_mesh(args.kv_shards),
+                             kv_axes=("data",))
+    else:
+        mesh_ctx.clear_context()
     model = Model(cfg)
     params = model.init(jax.random.key(args.seed))
-
     engine = ServeEngine(model, params, EngineConfig(
         slots=args.slots, max_len=args.max_len,
         preempt_mode=args.preempt_mode, retain_frames=args.retain_frames,
@@ -100,6 +138,38 @@ def main() -> None:
         spill_path=args.spill_path,
         max_fused_steps=args.max_fused_steps,
         prefix_index=args.prefix_index))
+    return model, params, engine
+
+
+def paged_impl(cfg) -> str | None:
+    """The paged-decode impl the decode step resolves to (None for the
+    batch layout, which reads no pages)."""
+    if cfg.kv_layout == "batch":
+        return None
+    from repro.kernels.paged_decode.ops import resolve_impl
+    ctx = mesh_ctx.get_context()
+    tp = 1 if ctx is None else ctx.tp
+    return resolve_impl(cfg.paged_kernel, cfg.n_heads // tp,
+                        cfg.n_heads // cfg.n_kv_heads)
+
+
+def main(argv: list[str] | None = None,
+         requests: list[Request] | None = None) -> dict:
+    """Serve and return the report the command line prints.  ``requests``
+    replaces the generated ones (not with ``--trace``)."""
+    enable_compile_cache()
+    args = parse_args(argv)
+    _, _, engine = build(args)
+    return run(args, engine, requests)
+
+
+def run(args: argparse.Namespace, engine: ServeEngine,
+        requests: list[Request] | None = None) -> dict:
+    """Serve on a built engine until every request is done, shut the
+    engine down, and return the report.  Requests the caller already
+    admitted into slots are served too, so ``requests=[]`` finishes
+    those."""
+    cfg = engine.model.cfg
     sched = Scheduler(engine, SchedulerConfig(window=args.sched_window,
                                               aging_steps=args.aging_steps))
     t0 = time.monotonic()
@@ -113,24 +183,28 @@ def main() -> None:
             out_len_long=args.max_new, vocab_size=cfg.vocab_size)
         done = replay(generate(tcfg), sched)
     else:
-        rng = np.random.default_rng(args.seed)
-        sched.submit([Request(uid=i,
-                              prompt=rng.integers(0, cfg.vocab_size,
-                                                  args.prompt_len)
-                              .astype(np.int32),
-                              max_new_tokens=args.max_new)
-                      for i in range(args.requests)])
+        if requests is None:
+            rng = np.random.default_rng(args.seed)
+            requests = [Request(uid=i,
+                                prompt=rng.integers(0, cfg.vocab_size,
+                                                    args.prompt_len)
+                                .astype(np.int32),
+                                max_new_tokens=args.max_new)
+                        for i in range(args.requests)]
+        sched.submit(requests)
         done = sched.run()
     dt = time.monotonic() - t0
     stats = engine.shutdown()
     total_new = sum(len(r.output) for r in done)
-    print(json.dumps({
+    return {
         "completed": len(done), "new_tokens": total_new,
         "tokens_per_s": round(total_new / dt, 1),
+        "paged_impl": paged_impl(cfg),
+        "shared_prompt_tokens": stats["shared_prompt_tokens"],
         "outputs": {r.uid: r.output[:8] for r in done},
         "telemetry": stats["telemetry"],
-    }, indent=1))
+    }
 
 
 if __name__ == "__main__":
-    main()
+    print(json.dumps(main(), indent=1))

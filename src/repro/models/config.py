@@ -90,8 +90,8 @@ class ModelConfig:
     #: pooled-store callers): "auto" -- fused VM-walking Pallas kernels on
     #: TPU, composed jnp ops elsewhere; "fused" -- force the Pallas path
     #: (interpret mode off-TPU); "composed" -- force the reference ops.
-    #: Fused needs whole KV-head groups per tensor-parallel shard; the
-    #: dispatch layer falls back to "composed" otherwise.
+    #: Fused needs whole KV-head groups per tensor-parallel shard: "auto"
+    #: then picks "composed", and an explicit "fused" is a ValueError.
     paged_kernel: str = "auto"
     kv_dtype: str | None = None          # KV cache dtype override (e.g.
                                          # "float8_e4m3fn" -- halves KV traffic)

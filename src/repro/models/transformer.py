@@ -8,7 +8,7 @@ required to compile 80-layer models for 512 devices in reasonable time.
 
 Cache layouts (cfg.kv_layout):
   "batch" -- k/v: [B, Hkv, S_max, hd] per attention layer (batch-sharded).
-  "paged" -- k/v pages: [n_pages, page_slots, Hkv, hd] per attention layer,
+  "paged" -- k/v pages: [n_pages, Hkv, page_slots, hd] per attention layer,
              pages cyclically owned by the KV mesh axes (the emulated-memory
              scheme, `repro.core.emem`); decode merges per-shard partials.
 """
@@ -173,9 +173,9 @@ def init_cache(cfg: ModelConfig, batch_size: int, max_len: int,
                 else:
                     n_pages = batch_size * max_pages
                 entry = {
-                    "k_pages": jnp.zeros((np_, n_pages, slots, hkv, hd),
+                    "k_pages": jnp.zeros((np_, n_pages, hkv, slots, hd),
                                          kv_dtype),
-                    "v_pages": jnp.zeros((np_, n_pages, slots, hkv, hd),
+                    "v_pages": jnp.zeros((np_, n_pages, hkv, slots, hd),
                                          kv_dtype),
                 }
             else:

@@ -40,7 +40,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.emem_vm.layout import frame_rows
@@ -62,7 +61,7 @@ def paged_decode_attention(cfg: ModelConfig, q, k_new, v_new, k_pages,
                            v_pages, lengths, vm: dict | None = None,
                            write_mask=None):
     """q: [B, H, hd]; k_new/v_new: [B, Hkv, hd] (rope'd at position len-1);
-    k/v_pages: [n_pages, slots, Hkv, hd] global.  Returns (out, pages').
+    k/v_pages: [n_pages, Hkv, slots, hd] global.  Returns (out, pages').
 
     ``vm`` is the BlockManager translation state documented in the module
     docstring; without it the fixed arithmetic mapping applies.
@@ -121,12 +120,12 @@ def paged_decode_attention(cfg: ModelConfig, q, k_new, v_new, k_pages,
         return out, kp_l, vp_l
 
     kv_spec = P(kv_axes if len(kv_axes) > 1 else kv_axes[0])
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(P(None, tp_axis, None), P(), P(), kv_spec, kv_spec, P(),
                   P(), P(), P(), P()),
         out_specs=(P(None, tp_axis, None), kv_spec, kv_spec),
-        check_rep=False)
+        check_vma=False)
     return fn(q, k_new, v_new, k_pages, v_pages, lengths, bt, fl, fr,
               write_mask)
 
@@ -190,7 +189,7 @@ def read_frame_pages(cache: dict, frames) -> list:
     payloads = [dict() for _ in range(len(idx))]
     for key, entry in paged_entries(cache):
         rows = _frame_rows(idx, entry["k_pages"].shape[1])
-        k = np.asarray(entry["k_pages"][:, rows])      # [np_, n, slots, ...]
+        k = np.asarray(entry["k_pages"][:, rows])      # [np_, n, Hkv, ...]
         v = np.asarray(entry["v_pages"][:, rows])
         for i in range(len(idx)):
             payloads[i][key] = (k[:, i], v[:, i])
@@ -224,7 +223,7 @@ def write_frame_pages(cache: dict, assignments) -> dict:
 def cow_copy_pages(cache: dict, copies) -> dict:
     """Apply BlockManager CowCopy records to every attention layer's pages.
 
-    Device-side row copies (k/v_pages are [n_periods, n_pages, slots, ...]);
+    Device-side row copies (k/v_pages are [n_periods, n_pages, Hkv, ...]);
     host-driven, outside the jitted decode -- COW is a control-plane event.
     """
     if not copies:

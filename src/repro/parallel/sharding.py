@@ -139,7 +139,7 @@ def cache_pspecs(cache_shapes, mesh: Mesh, *, dp_axes: Sequence[str],
 
     batch-layout k/v [L,B,Hkv,S,hd]: batch over DP; KV heads over TP when
     divisible, else the sequence dim over TP (flash-decode merge territory).
-    paged k/v pages [L,NP,slots,Hkv,hd]: pages over the EMem owner axes.
+    paged k/v pages [L,NP,Hkv,slots,hd]: pages over the EMem owner axes.
     SSM states: batch over DP, heads/channels over TP when divisible.
     """
     dp, kv = tuple(dp_axes), tuple(kv_axes)

@@ -87,6 +87,28 @@ from repro.models.model import Model
 from repro.serve.telemetry import Telemetry
 
 
+def _init_cache(model: Model, slots: int, max_len: int) -> dict:
+    """The zero cache pytree.  Under a mesh context with several KV shards
+    the page pool is created already sharded over the KV axes, so each
+    device holds its share of the frames from the start; every other leaf
+    is replicated."""
+    from repro.parallel import mesh_ctx
+    from repro.parallel.paged_attention import paged_entries
+    ctx = mesh_ctx.get_context()
+    if ctx is None or ctx.n_kv_shards == 1 \
+            or model.cfg.kv_layout not in ("paged", "pooled"):
+        return model.init_cache(slots, max_len)
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    kv = ctx.kv_axes if len(ctx.kv_axes) > 1 else ctx.kv_axes[0]
+    pages = NamedSharding(ctx.mesh, P(None, kv))
+    shapes = jax.eval_shape(lambda: model.init_cache(slots, max_len))
+    shardings = jax.tree.map(lambda _: NamedSharding(ctx.mesh, P()), shapes)
+    for key, _ in paged_entries(shapes):
+        shardings[key] = {"k_pages": pages, "v_pages": pages}
+    return jax.jit(lambda: model.init_cache(slots, max_len),
+                   out_shardings=shardings)()
+
+
 @dataclasses.dataclass
 class Request:
     uid: int
@@ -141,7 +163,7 @@ class ServeEngine:
         self.model = model
         self.params = params
         self.ecfg = ecfg
-        self.cache = model.init_cache(ecfg.slots, ecfg.max_len)
+        self.cache = _init_cache(model, ecfg.slots, ecfg.max_len)
         self.lengths = jnp.zeros((ecfg.slots,), jnp.int32)
         self.slot_req: list[Request | None] = [None] * ecfg.slots
         self.budget = np.zeros(ecfg.slots, np.int64)
@@ -241,10 +263,10 @@ class ServeEngine:
         every other in-flight slot's newest KV position (and SSM state) with
         pad-token state.
 
-        The sync matters: XLA CPU async dispatch (observed on jax 0.4.37)
-        corrupts results when executions of the same executable overlap, as
-        they do in the prefill loop which never reads its outputs between
-        tokens.  Materializing ``sampled`` blocks on the execution, which
+        The sync matters: XLA CPU async dispatch has been seen to corrupt
+        results when executions of the same executable overlap, as they do
+        in the prefill loop which never reads its outputs between tokens.
+        Materializing ``sampled`` blocks on the execution, which
         serializes consecutive dispatches; a fused run is ONE dispatch, so
         the same one-sync-per-dispatch rule costs it a single sync at loop
         exit (see :meth:`_step_fused`).  (Host-side buffers are also always

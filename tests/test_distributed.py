@@ -18,7 +18,10 @@ def run_with_devices(body: str, n_devices: int = 8) -> str:
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={n_devices}"
         import jax, jax.numpy as jnp, numpy as np
-        from repro.launch.mesh import compat_make_mesh as make_mesh
+        from jax.sharding import AxisType
+        def make_mesh(shape, axes):
+            return jax.make_mesh(shape, axes,
+                                 axis_types=(AxisType.Auto,) * len(axes))
         """) + textwrap.dedent(body)
     env = dict(os.environ, PYTHONPATH=SRC)
     env.pop("XLA_FLAGS", None)
@@ -630,9 +633,9 @@ def test_paged_decode_fused_matches_composed_on_meshes(n_devices):
         q = jnp.asarray(rng.normal(size=(B, 8, hd)).astype(np.float32))
         k_new = jnp.asarray(rng.normal(size=(B, hkv, hd)).astype(np.float32))
         v_new = jnp.asarray(rng.normal(size=(B, hkv, hd)).astype(np.float32))
-        kp = jnp.asarray(rng.normal(size=(n_pages, ps, hkv, hd))
+        kp = jnp.asarray(rng.normal(size=(n_pages, hkv, ps, hd))
                          .astype(np.float32))
-        vp = jnp.asarray(rng.normal(size=(n_pages, ps, hkv, hd))
+        vp = jnp.asarray(rng.normal(size=(n_pages, hkv, ps, hd))
                          .astype(np.float32))
         lengths = jnp.asarray([21, 9], jnp.int32)
         bt = np.full((B, 8), -1, np.int32)

@@ -151,9 +151,9 @@ def _run_shard(rng, impl, *, b=3, max_lpages=4, page_slots=8, hkv=2, group=2,
     q = jnp.asarray(rng.normal(size=(b, hl, hd)).astype(np.float32))
     k_new = jnp.asarray(rng.normal(size=(b, hkv, hd)).astype(np.float32))
     v_new = jnp.asarray(rng.normal(size=(b, hkv, hd)).astype(np.float32))
-    kp = jnp.asarray(rng.normal(size=(n_frames, page_slots, hkv, hd))
+    kp = jnp.asarray(rng.normal(size=(n_frames, hkv, page_slots, hd))
                      .astype(np.float32))
-    vp = jnp.asarray(rng.normal(size=(n_frames, page_slots, hkv, hd))
+    vp = jnp.asarray(rng.normal(size=(n_frames, hkv, page_slots, hd))
                      .astype(np.float32))
     bt, fl, fr = _mk_vm(rng, b, max_lpages, page_slots, lengths,
                         shared_page0=shared_page0)
@@ -192,6 +192,29 @@ def test_paged_decode_fused_matches_composed(rng, window, lengths):
     composed = _run_shard(np.random.default_rng(seed), "composed",
                           window=window, lengths=lengths)
     _assert_shard_match(fused, composed)
+
+
+@pytest.mark.parametrize("window", [None, 40])
+@pytest.mark.parametrize("page_slots,lengths", [(16, (33, 16, 61)),
+                                                (256, (300, 256, 1000))])
+def test_paged_decode_fused_matches_composed_tpu_page_slots(
+        rng, page_slots, lengths, window):
+    """The ``[n_pages, Hkv, slots, D]`` layout at the page sizes the chip
+    compiles (16 and 256 slots): the write lands inside a page and at its
+    last slot, page 0 is a shared read-only frame, and the read walks
+    several pages per sequence.  Pages must match byte for byte; at 256
+    slots XLA sums the composed scores in another order than the kernel,
+    so the running max may differ in its last bit."""
+    seed = int(rng.integers(1 << 31))
+    kw = dict(page_slots=page_slots, lengths=lengths, window=window,
+              shared_page0=True)
+    fused = _run_shard(np.random.default_rng(seed), "fused", **kw)
+    composed = _run_shard(np.random.default_rng(seed), "composed", **kw)
+    for f, c in zip(fused[3:], composed[3:]):
+        np.testing.assert_array_equal(np.asarray(f), np.asarray(c))
+    for f, c in zip(fused[:3], composed[:3]):
+        np.testing.assert_allclose(np.asarray(f), np.asarray(c),
+                                   rtol=1e-5, atol=1e-5)
 
 
 def test_paged_decode_fused_write_drop(rng):
@@ -236,15 +259,20 @@ def test_paged_decode_fused_no_vm_identity_tables(rng):
     _assert_shard_match(fused, composed)
 
 
-def test_paged_decode_resolve_impl():
+def test_paged_decode_resolve_impl(monkeypatch):
     """Dispatch policy: 'composed' always honored; 'fused' honored whenever
     the local head count splits into whole KV groups (interpret mode makes
-    it CPU-runnable); ragged groups always fall back."""
+    it CPU-runnable) and refused otherwise; 'auto' is fused only on TPU."""
     assert pd_ops.resolve_impl("composed", 8, 2) == "composed"
     assert pd_ops.resolve_impl("fused", 8, 2) == "fused"
-    assert pd_ops.resolve_impl("fused", 7, 2) == "composed"  # ragged group
-    auto = pd_ops.resolve_impl("auto", 8, 2)
-    assert auto in ("fused", "composed")       # fused iff actually on TPU
+    with pytest.raises(ValueError, match="whole KV-head groups"):
+        pd_ops.resolve_impl("fused", 7, 2)              # ragged group
+    with pytest.raises(ValueError, match="unknown paged_kernel"):
+        pd_ops.resolve_impl("pallas", 8, 2)
+    assert pd_ops.resolve_impl("auto", 8, 2) == "composed"    # CPU
+    monkeypatch.setattr(pd_ops, "_on_tpu", lambda: True)
+    assert pd_ops.resolve_impl("auto", 8, 2) == "fused"
+    assert pd_ops.resolve_impl("auto", 7, 2) == "composed"
 
 
 # -- mamba2 SSD -------------------------------------------------------------------
