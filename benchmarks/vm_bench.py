@@ -53,6 +53,7 @@ from benchmarks.common import print_csv, row, timeit
 from repro.core import emem
 from repro.emem_vm import EMemVM, VMConfig
 from repro.emem_vm import vm as vm_mod
+from repro.serve.telemetry import step_summary
 
 _JSON_PATH = os.path.join(os.path.dirname(__file__), "..", "BENCH_vm.json")
 
@@ -626,7 +627,8 @@ _SLO_TRACE = dict(seed=11, n_requests=18, arrival_rate=0.35, n_prompts=6,
 
 def _run_slo(layout: str, preempt_mode: str, pool: int, slots: int,
              retain: int, max_fused: int | None = None):
-    """One trace replay; returns (per-uid outputs, telemetry summary).
+    """One trace replay; returns (per-uid outputs, the step-denominated
+    telemetry summary).
     ``max_fused`` overrides the engine's fused-decode cap (None: the
     EngineConfig default) -- the committed baseline was measured
     step-at-a-time, and fusion promises byte-identical telemetry, so
@@ -644,7 +646,8 @@ def _run_slo(layout: str, preempt_mode: str, pool: int, slots: int,
         sched = Scheduler(engine, SchedulerConfig(window=4))
         done = replay(generate(TraceConfig(**_SLO_TRACE)), sched)
     stats = engine.shutdown()
-    return {r.uid: tuple(r.output) for r in done}, stats["telemetry"]
+    return {r.uid: tuple(r.output) for r in done}, \
+        step_summary(stats["telemetry"])
 
 
 def _slo_rows(record: dict, smoke: bool = False) -> list[dict]:
@@ -781,7 +784,8 @@ def _dispatch_rows(record: dict, smoke: bool = False) -> list[dict]:
     out_p, _, _ = _run_dispatch(fused, layout="paged")
     assert out_f == out_s == out_p, \
         "fused decode changed decoded tokens (vs stepwise / reserved)"
-    assert st_f["telemetry"] == st_s["telemetry"], \
+    assert step_summary(st_f["telemetry"]) == \
+        step_summary(st_s["telemetry"]), \
         "fused decode changed decode-step telemetry"
     # best-of-2 timed replays per mode: wall time on a toy model is noisy
     wall_f = min(_run_dispatch(fused)[2], _run_dispatch(fused)[2])

@@ -152,6 +152,7 @@ def paged_kv_write(k_new: jax.Array, v_new: jax.Array, k_pages: jax.Array,
         # inputs are counted including the scalar-prefetch operands
         input_output_aliases={7: 0, 8: 1},
         interpret=interpret,
+        name="paged_kv_write",
     )(block_table.reshape(-1).astype(jnp.int32),
       lengths.astype(jnp.int32), frame_ro.astype(jnp.int32),
       write_mask.astype(jnp.int32), meta.astype(jnp.int32),
@@ -267,6 +268,7 @@ def paged_gather_attend(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
             jax.ShapeDtypeStruct((b, hkv_loc, g, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="paged_gather_attend",
     )(block_table.reshape(-1).astype(jnp.int32), lengths.astype(jnp.int32),
       meta.astype(jnp.int32), q, k_pages, v_pages)
     return acc, m, l

@@ -12,9 +12,11 @@ pool over N devices, each holding 1/N of the frames from the start:
 
 ``--trace`` switches from submit-everything-up-front to a seeded synthetic
 trace (Poisson arrivals, Zipf prompt popularity, bimodal lengths) replayed
-against the engine's decode-step clock, so requests genuinely queue; the
-output JSON then includes the per-request SLO telemetry (p50/p95/p99 TTFT,
-inter-token latency, queue wait -- all in decode steps):
+against the engine's decode-step clock, so requests genuinely queue.  The
+output JSON's ``telemetry`` holds the SLO summary: p50/p95/p99 TTFT,
+inter-token latency and queue wait, in decode steps (``ttft_steps``,
+``itl_steps``, ``queue_wait_steps``) and in seconds of the host's clock
+(``ttft_s``, ``itl_s``, ``queue_wait_s``):
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-0.6b --smoke \
       --trace --requests 24 --arrival-rate 0.3 --zipf-alpha 1.2

@@ -71,6 +71,18 @@ host-side from that buffer -- byte-for-byte what the stepwise path would
 have recorded.  ``EngineConfig.max_fused_steps=1`` reproduces
 step-at-a-time dispatch exactly.
 
+**Host spans.**  Each boundary of the hot loop opens a span of the
+engine's :class:`Telemetry` (``self.metrics.span``; a no-op until
+``metrics.start_spans()``): ``admit`` and ``step`` around the two entry
+points; inside them ``prefix_match`` (``begin_seq`` / ``restore_seq``),
+``grow`` (``_grow``, copy-on-write and preemption included),
+``sync_tables`` (a block-table push), ``inputs`` (device inputs built from
+host values), ``dispatch`` (the call into a jitted decode until it
+returns), ``device_wait`` (a host read of a device value), ``stage_run``
+(planning a fused run), ``replay`` (the host bookkeeping of a run's
+tokens) and ``page_io`` (page moves between tiers and copy-on-write).  The
+BlockManager itself carries no telemetry.
+
 ``ServeEngine`` is a context manager: ``with ServeEngine(...) as eng:``
 guarantees the shutdown leak detector runs even when the body raises
 (active requests are aborted first so the original exception propagates).
@@ -78,6 +90,7 @@ guarantees the shutdown leak detector runs even when the body raises
 from __future__ import annotations
 
 import dataclasses
+import time
 
 import jax
 import jax.numpy as jnp
@@ -190,7 +203,11 @@ class ServeEngine:
                          "swapped": 0, "swap_resumed": 0, "aborted": 0,
                          "decode_steps": 0, "dispatches": 0,
                          "shared_prompt_tokens": 0, "leaked_frames": 0,
-                         "score_cache_hits": 0}
+                         "score_cache_hits": 0,
+                         # tokens admit() ran through the decode path
+                         "prefill_tokens": 0,
+                         # bytes of block tables pushed to the device
+                         "table_push_bytes": 0}
         cfg = model.cfg
         if cfg.kv_layout in ("paged", "pooled"):
             from repro.emem_vm import BlockManager, PageIO
@@ -228,9 +245,9 @@ class ServeEngine:
                 prefix_index=ecfg.prefix_index)
             from repro.parallel.paged_attention import (read_frame_pages,
                                                         write_frame_pages)
-            self.blocks.page_io = PageIO(
-                read=lambda frames: read_frame_pages(self.cache, frames),
-                write=self._apply_frame_writes)
+            self.blocks.page_io = PageIO(read=self._read_frame_pages,
+                                         write=self._apply_frame_writes)
+            self._read_pages = read_frame_pages
             self._write_frame_pages = write_frame_pages
             self.blocks.dirty = True     # push the initial (empty) tables
         else:
@@ -269,25 +286,37 @@ class ServeEngine:
         Materializing ``sampled`` blocks on the execution, which
         serializes consecutive dispatches; a fused run is ONE dispatch, so
         the same one-sync-per-dispatch rule costs it a single sync at loop
-        exit (see :meth:`_step_fused`).  (Host-side buffers are also always
-        *copied* in with ``jnp.array`` -- ``jnp.asarray`` zero-copies numpy
-        memory, racing later in-place mutation of the same buffer.)
+        exit (see :meth:`_step_fused`).  (The host-side ``toks`` and
+        ``write_mask`` are always *copied* in with ``jnp.array`` --
+        ``jnp.asarray`` zero-copies numpy memory, racing later in-place
+        mutation of the same buffer.)
         """
         from repro.serve.fused_decode import sampled_decode_step
         if write_mask is None:
             write_mask = np.ones(self.ecfg.slots, bool)
-        sampled, logits, cache = sampled_decode_step(
-            self.model, params, toks, cache, lengths, jnp.array(write_mask))
-        sampled = np.asarray(sampled)    # the one host transfer + sync
+        span = self.metrics.span
+        with span("inputs"):
+            toks, write_mask = jnp.array(toks), jnp.array(write_mask)
+        with span("dispatch", steps=1):
+            sampled, logits, cache = sampled_decode_step(
+                self.model, params, toks, cache, lengths, write_mask)
+        with span("device_wait"):
+            sampled = np.asarray(sampled)    # the one host transfer + sync
         self.counters["decode_steps"] += 1
         self.counters["dispatches"] += 1
         self.metrics.clock.tick()
         return sampled, logits, cache
 
     # -- frame management (both paged layouts, via the BlockManager) ---------
+    def _read_frame_pages(self, frames) -> list:
+        """PageIO read callback: snapshot device frames to host payloads."""
+        with self.metrics.span("page_io", pages=len(frames)):
+            return self._read_pages(self.cache, frames)
+
     def _apply_frame_writes(self, assignments) -> None:
         """PageIO write callback: scatter host payloads into device frames."""
-        self.cache = self._write_frame_pages(self.cache, assignments)
+        with self.metrics.span("page_io", pages=len(assignments)):
+            self.cache = self._write_frame_pages(self.cache, assignments)
 
     def _slot_state_read(self, slot: int) -> dict:
         """Snapshot a slot's non-paged per-slot cache state (SSM conv/ssd
@@ -326,21 +355,28 @@ class ServeEngine:
         if self.blocks is None:
             return True
         from repro.emem_vm import OutOfFrames
-        while True:
-            try:
-                copies = self.blocks.ensure_writable(slot, new_len - 1)
-            except OutOfFrames:
-                victim = max(
-                    (i for i, r in enumerate(self.slot_req) if r is not None),
-                    key=lambda s: self._admit_seq[s])
-                self._preempt(victim, lengths)
-                if victim == slot:
-                    return False
-                continue
-            if copies:
-                from repro.parallel.paged_attention import cow_copy_pages
-                self.cache = cow_copy_pages(self.cache, copies)
-            return True
+        with self.metrics.span("grow") as sp:
+            preemptions = 0
+            while True:
+                try:
+                    copies = self.blocks.ensure_writable(slot, new_len - 1)
+                except OutOfFrames:
+                    victim = max(
+                        (i for i, r in enumerate(self.slot_req)
+                         if r is not None),
+                        key=lambda s: self._admit_seq[s])
+                    self._preempt(victim, lengths)
+                    preemptions += 1
+                    sp["preemptions"] = preemptions
+                    if victim == slot:
+                        return False
+                    continue
+                if copies:
+                    from repro.parallel.paged_attention import cow_copy_pages
+                    sp["cow_pages"] = len(copies)
+                    with self.metrics.span("page_io", pages=len(copies)):
+                        self.cache = cow_copy_pages(self.cache, copies)
+                return True
 
     def _is_complete(self, req: Request, cur_len: int) -> bool:
         """The post-decode completion conditions, evaluable host-side: used
@@ -408,9 +444,14 @@ class ServeEngine:
     def _sync_vm(self) -> None:
         """Push the BlockManager tables into the cache pytree if changed."""
         if self.blocks is not None and self.blocks.dirty:
-            self.cache["vm"] = {k: jnp.array(v)
-                                for k, v in self.blocks.tables().items()}
-            self.blocks.dirty = False
+            with self.metrics.span("sync_tables") as sp:
+                tables = self.blocks.tables()
+                self.cache["vm"] = {k: jnp.array(v)
+                                    for k, v in tables.items()}
+                self.blocks.dirty = False
+                nbytes = sum(v.nbytes for v in tables.values())
+                sp["bytes"] = nbytes
+            self.counters["table_push_bytes"] += nbytes
 
     def pool_stats(self) -> dict:
         if self.blocks is None:
@@ -528,6 +569,13 @@ class ServeEngine:
             raise RuntimeError(
                 "inadmissible request (prompt too long for max_len, or no "
                 "free-frame headroom)")
+        with self.metrics.span("admit", uid=req.uid, prompt=len(req.prompt),
+                               shared=0, prefilled=0) as sp:
+            self._admit(req, slot, sp)
+
+    def _admit(self, req: Request, slot: int, sp) -> None:
+        """The body of :meth:`admit`; ``sp`` is its span."""
+        span = self.metrics.span
         toks = self._tokens_for(req)
         self.slot_req[slot] = req
         self.budget[slot] = req.max_new_tokens - len(req.output)
@@ -540,21 +588,25 @@ class ServeEngine:
             # would zero (lengths, committed KV, the whole slot state)
             swap_in0 = self.blocks.counters["swap_in_pages"]
             spill_in0 = self.blocks.counters["spill_in_pages"]
-            self.blocks.restore_seq(slot, swap["tag"], toks)
+            with span("prefix_match") as pm:
+                self.blocks.restore_seq(slot, swap["tag"], toks)
+                swap_in = self.blocks.counters["swap_in_pages"] - swap_in0
+                pm["swap_in_pages"] = swap_in
             self.metrics.on_admit(
-                req, resumed=True,
-                swap_in_pages=self.blocks.counters["swap_in_pages"]
-                - swap_in0,
+                req, resumed=True, swap_in_pages=swap_in,
                 spill_in_pages=self.blocks.counters["spill_in_pages"]
                 - spill_in0)
-            self._slot_state_write(slot, swap["slot_state"])
+            with span("inputs"):
+                self._slot_state_write(slot, swap["slot_state"])
             start = int(swap["committed"])
             req._next = swap["next"]
             del req._swap
             self.counters["swap_resumed"] += 1
-            lengths = np.array(self.lengths)
+            with span("device_wait"):
+                lengths = np.array(self.lengths)
             lengths[slot] = start
-            self.lengths = jnp.array(lengths)
+            with span("inputs"):
+                self.lengths = jnp.array(lengths)
             self._kv_committed[slot] = start
             if start >= len(toks):
                 # fully committed: KV, recurrent state and the pending next
@@ -565,8 +617,11 @@ class ServeEngine:
             self._reset_slot(slot)
             shared = 0
             if self.blocks is not None:
-                shared = self.blocks.begin_seq(slot, toks)
+                with span("prefix_match") as pm:
+                    shared = self.blocks.begin_seq(slot, toks)
+                    pm["shared"] = shared
                 self.counters["shared_prompt_tokens"] += shared
+                sp["shared"] = shared
             self.metrics.on_admit(
                 req, shared_tokens=shared,
                 match_depth_pages=-(-shared // self.page_slots)
@@ -574,7 +629,8 @@ class ServeEngine:
             start = min(shared, len(toks) - 1)
         mask = np.zeros(self.ecfg.slots, bool)
         mask[slot] = True                # only this slot commits KV writes
-        lengths = np.array(self.lengths)
+        with span("device_wait"):
+            lengths = np.array(self.lengths)
         # invariant allocations hoisted out of the prefill loop (prep for
         # chunked prefill): the token batch is reused across steps, and the
         # device lengths advance from a base with ``.at[slot].set`` instead
@@ -584,41 +640,49 @@ class ServeEngine:
         # dispatch the in-flight decode would race the next iteration's
         # in-place mutation
         tok_batch = np.zeros((self.ecfg.slots, 1), np.int32)
-        base = jnp.array(lengths)
+        with span("inputs"):
+            base = jnp.array(lengths)
         for t in range(start, len(toks)):
             lengths[slot] = t + 1
-            self.lengths = base.at[slot].set(t + 1)
+            with span("inputs"):
+                self.lengths = base.at[slot].set(t + 1)
             n_pre = len(self.preempted)
             if not self._grow(slot, t + 1, lengths):
+                sp["prefilled"] = t - start
                 return          # preempted mid-prefill; requeued for retry
             if len(self.preempted) != n_pre:
                 # a growth preemption zeroed a victim's length host-side;
                 # refresh the device base to match
-                base = jnp.array(lengths)
+                with span("inputs"):
+                    base = jnp.array(lengths)
             tok_batch[slot, 0] = toks[t]
             self._sync_vm()
             sampled, _, self.cache = self._decode(
-                self.params, jnp.array(tok_batch), self.cache, self.lengths,
-                mask)
+                self.params, tok_batch, self.cache, self.lengths, mask)
             self._kv_committed[slot] = t + 1
+            self.counters["prefill_tokens"] += 1
+        sp["prefilled"] = len(toks) - start
         req._next = int(sampled[slot])
         self.metrics.on_token(req, len(req.output))
         self.counters["admitted"] += 1
 
     def _reset_slot(self, slot: int) -> None:
-        lengths = np.array(self.lengths)
+        span = self.metrics.span
+        with span("device_wait"):
+            lengths = np.array(self.lengths)
         lengths[slot] = 0
-        self.lengths = jnp.array(lengths)
         self._kv_committed[slot] = 0
-        # per-slot state (recurrent SSM rows, batch-layout KV) is zeroed:
-        # recurrent state is cumulative, so a reused slot must not leak the
-        # previous tenant's state into the new sequence
-        from repro.parallel.paged_attention import slot_state_entries
-        for key, entry in slot_state_entries(self.cache):
-            e = dict(entry)
-            for name, arr in e.items():
-                e[name] = arr.at[:, slot].set(0)
-            self.cache[key] = e
+        with span("inputs"):
+            self.lengths = jnp.array(lengths)
+            # per-slot state (recurrent SSM rows, batch-layout KV) is
+            # zeroed: recurrent state is cumulative, so a reused slot must
+            # not leak the previous tenant's state into the new sequence
+            from repro.parallel.paged_attention import slot_state_entries
+            for key, entry in slot_state_entries(self.cache):
+                e = dict(entry)
+                for name, arr in e.items():
+                    e[name] = arr.at[:, slot].set(0)
+                self.cache[key] = e
 
     # -- decode -------------------------------------------------------------
     def _fused_horizon(self, order, lengths, max_steps: int | None) -> int:
@@ -660,63 +724,74 @@ class ServeEngine:
         opportunity can occur inside the run, so none of that code needs
         to run here."""
         from repro.serve.fused_decode import fused_decode_run
+        span = self.metrics.span
         cap = int(self.ecfg.max_fused_steps)
-        active = np.zeros(self.ecfg.slots, bool)
-        toks = np.zeros((self.ecfg.slots, 1), np.int32)
-        staged_lp = np.full((self.ecfg.slots, cap), -1, np.int32)
-        staged_fm = np.full((self.ecfg.slots, cap), -1, np.int32)
-        lengths0 = np.array(self.lengths)
-        for i in order:
-            active[i] = True
-            toks[i, 0] = self.slot_req[i]._next
-        if plan is not None:
-            for st in plan.allocs:
-                if st.k_hit < horizon:   # applied by iteration k_hit's body;
-                    staged_lp[st.seq, st.k_hit] = st.lpage
-                    staged_fm[st.seq, st.k_hit] = st.frame
-                # k_hit == horizon stagings commit host-side only -- the
-                # dirty flag re-syncs the device tables before the next
-                # dispatch, exactly like a stepwise trailing prefetch
-        eos = -1 if self.ecfg.eos_id is None else int(self.ecfg.eos_id)
-        self._sync_vm()
-        buf, n_done, self.cache, self.lengths = fused_decode_run(
-            self.model, cap, self.params,
-            jnp.array(toks), self.cache, self.lengths, jnp.array(active),
-            jnp.int32(horizon), jnp.int32(eos),
-            jnp.array(staged_lp), jnp.array(staged_fm))
-        buf = np.asarray(buf)            # the one host sync of the run
-        n = int(n_done)
-        if plan is not None:
-            self.blocks.commit_fused_run(plan, n)
-        self.counters["decode_steps"] += n
-        self.counters["dispatches"] += 1
-        c0 = self.metrics.clock.now()
-        self.metrics.clock.tick(n)
-        # token attribution: iteration k fed the pending ``_next`` (k == 0)
-        # or buf[k-1], and its decode (at clock c0 + k + 1) sampled buf[k]
-        for k in range(n):
+        with span("device_wait"):
+            lengths0 = np.array(self.lengths)
+        with span("inputs"):
+            active = np.zeros(self.ecfg.slots, bool)
+            toks = np.zeros((self.ecfg.slots, 1), np.int32)
+            staged_lp = np.full((self.ecfg.slots, cap), -1, np.int32)
+            staged_fm = np.full((self.ecfg.slots, cap), -1, np.int32)
             for i in order:
+                active[i] = True
+                toks[i, 0] = self.slot_req[i]._next
+            if plan is not None:
+                for st in plan.allocs:
+                    if st.k_hit < horizon:   # applied by iteration k_hit;
+                        staged_lp[st.seq, st.k_hit] = st.lpage
+                        staged_fm[st.seq, st.k_hit] = st.frame
+                    # k_hit == horizon stagings commit host-side only --
+                    # the dirty flag re-syncs the device tables before the
+                    # next dispatch, exactly like a stepwise trailing
+                    # prefetch
+            eos = -1 if self.ecfg.eos_id is None else int(self.ecfg.eos_id)
+            run_in = (jnp.array(active), jnp.int32(horizon), jnp.int32(eos),
+                      jnp.array(staged_lp), jnp.array(staged_fm))
+            toks_in = jnp.array(toks)
+        self._sync_vm()
+        with span("dispatch", steps=horizon):
+            buf, n_done, self.cache, self.lengths = fused_decode_run(
+                self.model, cap, self.params, toks_in, self.cache,
+                self.lengths, *run_in)
+        with span("device_wait"):
+            buf = np.asarray(buf)        # the one host sync of the run
+            n = int(n_done)
+        seen = time.perf_counter_ns()    # when the host saw the run's tokens
+        with span("replay", tokens=n * len(order)):
+            if plan is not None:
+                self.blocks.commit_fused_run(plan, n)
+            self.counters["decode_steps"] += n
+            self.counters["dispatches"] += 1
+            c0 = self.metrics.clock.now()
+            self.metrics.clock.tick(n)
+            # token attribution: iteration k fed the pending ``_next``
+            # (k == 0) or buf[k-1], and its decode (at clock c0 + k + 1)
+            # sampled buf[k]
+            for k in range(n):
+                for i in order:
+                    req = self.slot_req[i]
+                    req.output.append(int(toks[i, 0]) if k == 0
+                                      else int(buf[k - 1, i]))
+                    self.metrics.on_token(req, len(req.output),
+                                          at=c0 + k + 1, seen_ns=seen)
+            for i in sorted(order):      # stepwise parity: slot-index order
                 req = self.slot_req[i]
-                req.output.append(int(toks[i, 0]) if k == 0
-                                  else int(buf[k - 1, i]))
-                self.metrics.on_token(req, len(req.output), at=c0 + k + 1)
-        for i in sorted(order):          # stepwise parity: slot-index order
-            req = self.slot_req[i]
-            new_len = int(lengths0[i]) + n
-            self._kv_committed[i] = new_len
-            req._next = int(buf[n - 1, i])
-            self.budget[i] -= n
-            hit_eos = (self.ecfg.eos_id is not None
-                       and req.output and req.output[-1] == self.ecfg.eos_id)
-            if self.budget[i] <= 0 or hit_eos or \
-                    new_len >= self.ecfg.max_len - 1:
-                req.done = True
-                self.slot_req[i] = None
-                self.counters["completed"] += 1
-                self.completed_reqs.append(req)
-                self.metrics.on_complete(req)
-                self._kv_committed[i] = 0
-                self._release(i)
+                new_len = int(lengths0[i]) + n
+                self._kv_committed[i] = new_len
+                req._next = int(buf[n - 1, i])
+                self.budget[i] -= n
+                hit_eos = (self.ecfg.eos_id is not None and req.output
+                           and req.output[-1] == self.ecfg.eos_id)
+                if self.budget[i] <= 0 or hit_eos or \
+                        new_len >= self.ecfg.max_len - 1:
+                    req.done = True
+                    self.slot_req[i] = None
+                    self.counters["completed"] += 1
+                    self.completed_reqs.append(req)
+                    self.metrics.on_complete(req)
+                    self._kv_committed[i] = 0
+                    self._release(i)
         return n
 
     def step(self, max_steps: int | None = None) -> int:
@@ -737,26 +812,39 @@ class ServeEngine:
         waits on the allocator.  A fused run never contains any of those
         events -- that is what makes it safe to fuse (see
         :meth:`_fused_horizon`)."""
+        with self.metrics.span("step", iterations=0, seqs=0) as sp:
+            return self._step(max_steps, sp)
+
+    def _step(self, max_steps: int | None, sp) -> int:
+        """The body of :meth:`step`; ``sp`` is its span, given the decode
+        iterations run and the sequences each decoded."""
+        span = self.metrics.span
         order = sorted((i for i, r in enumerate(self.slot_req)
                         if r is not None),
                        key=lambda s: self._admit_seq[s])
         if not order:
             return 0
-        lengths_np = np.asarray(self.lengths)
-        horizon = self._fused_horizon(order, lengths_np, max_steps)
-        plan = None
-        if horizon > 1 and self.blocks is not None:
-            plan = self.blocks.stage_fused_run(
-                order, [int(lengths_np[i]) for i in order], horizon)
-            if plan.n <= 1:              # immediate growth/COW: stepwise
-                self.blocks.cancel_fused_run(plan)
-                plan, horizon = None, 1
-            else:
-                horizon = plan.n
+        with span("device_wait"):
+            lengths_np = np.asarray(self.lengths)
+        with span("stage_run") as st:
+            horizon = self._fused_horizon(order, lengths_np, max_steps)
+            plan = None
+            if horizon > 1 and self.blocks is not None:
+                plan = self.blocks.stage_fused_run(
+                    order, [int(lengths_np[i]) for i in order], horizon)
+                if plan.n <= 1:          # immediate growth/COW: stepwise
+                    self.blocks.cancel_fused_run(plan)
+                    plan, horizon = None, 1
+                else:
+                    horizon = plan.n
+            st["horizon"] = horizon
         if horizon > 1:
-            return self._step_fused(order, horizon, plan)
+            n = self._step_fused(order, horizon, plan)
+            sp["iterations"], sp["seqs"] = n, len(order)
+            return n
         toks = np.zeros((self.ecfg.slots, 1), np.int32)
-        lengths = np.array(self.lengths)
+        with span("device_wait"):
+            lengths = np.array(self.lengths)
         for i in order:
             req = self.slot_req[i]
             if req is None:              # preempted by an earlier grow
@@ -767,7 +855,8 @@ class ServeEngine:
             if self._grow(i, int(lengths[i]), lengths) and \
                     self.slot_req[i] is not None and self.blocks is not None:
                 self.blocks.prefetch(i, int(lengths[i]))
-        self.lengths = jnp.array(lengths)
+        with span("inputs"):
+            self.lengths = jnp.array(lengths)
         active = [i for i, r in enumerate(self.slot_req) if r is not None]
         if not active:
             return 0
@@ -775,22 +864,25 @@ class ServeEngine:
         mask[active] = True
         self._sync_vm()
         sampled, _, self.cache = self._decode(
-            self.params, jnp.array(toks), self.cache, self.lengths, mask)
-        for i in active:
-            self._kv_committed[i] = int(lengths[i])
-            req = self.slot_req[i]
-            req._next = int(sampled[i])
-            self.metrics.on_token(req, len(req.output))
-            self.budget[i] -= 1
-            hit_eos = (self.ecfg.eos_id is not None
-                       and req.output and req.output[-1] == self.ecfg.eos_id)
-            if self.budget[i] <= 0 or hit_eos or \
-                    int(lengths[i]) >= self.ecfg.max_len - 1:
-                req.done = True
-                self.slot_req[i] = None
-                self.counters["completed"] += 1
-                self.completed_reqs.append(req)
-                self.metrics.on_complete(req)
-                self._kv_committed[i] = 0
-                self._release(i)
+            self.params, toks, self.cache, self.lengths, mask)
+        seen = time.perf_counter_ns()
+        with span("replay", tokens=len(active)):
+            for i in active:
+                self._kv_committed[i] = int(lengths[i])
+                req = self.slot_req[i]
+                req._next = int(sampled[i])
+                self.metrics.on_token(req, len(req.output), seen_ns=seen)
+                self.budget[i] -= 1
+                hit_eos = (self.ecfg.eos_id is not None and req.output
+                           and req.output[-1] == self.ecfg.eos_id)
+                if self.budget[i] <= 0 or hit_eos or \
+                        int(lengths[i]) >= self.ecfg.max_len - 1:
+                    req.done = True
+                    self.slot_req[i] = None
+                    self.counters["completed"] += 1
+                    self.completed_reqs.append(req)
+                    self.metrics.on_complete(req)
+                    self._kv_committed[i] = 0
+                    self._release(i)
+        sp["iterations"], sp["seqs"] = 1, len(active)
         return 1

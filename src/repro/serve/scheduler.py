@@ -181,24 +181,35 @@ class Scheduler:
         unchanged headroom would spin).  Returns the ids of those
         passed-over preemptees: their admissibility was never re-evaluated
         after their eviction, so the caller must not fuse past the next
-        step while one could be waiting on a free slot."""
+        step while one could be waiting on a free slot.
+
+        A pass over a non-empty queue is the engine telemetry's
+        ``schedule`` span (requests waiting at its start, requests
+        admitted); the ``admit`` spans of its admissions nest inside it."""
         tried: set[int] = set()
-        while self.queue:
-            slots = self.engine.free_slots()
-            if not slots:
-                break
-            idx = self._pick_next(tried)
-            if idx is None:
-                break
-            req = self.queue[idx]
-            del self.queue[idx]
-            self._age.pop(id(req), None)
-            self._score_cache.pop(id(req), None)
-            self.engine.admit(req, slots[0])
-            for p in self.engine.drain_preempted():
-                tried.add(id(p))
-                self.queue.appendleft(p)
-            self._drain_completed()   # an admission may preempt-complete
+        if not self.queue:
+            return tried
+        with self.engine.metrics.span("schedule",
+                                      waiting=len(self.queue)) as sp:
+            admitted = 0
+            while self.queue:
+                slots = self.engine.free_slots()
+                if not slots:
+                    break
+                idx = self._pick_next(tried)
+                if idx is None:
+                    break
+                req = self.queue[idx]
+                del self.queue[idx]
+                self._age.pop(id(req), None)
+                self._score_cache.pop(id(req), None)
+                self.engine.admit(req, slots[0])
+                admitted += 1
+                sp["admitted"] = admitted
+                for p in self.engine.drain_preempted():
+                    tried.add(id(p))
+                    self.queue.appendleft(p)
+                self._drain_completed()   # an admission may preempt-complete
         return tried
 
     def _requeue_preempted(self) -> None:

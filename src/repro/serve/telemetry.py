@@ -1,31 +1,36 @@
-"""Per-request SLO telemetry for the serving stack.
+"""Per-request SLO telemetry and host spans for the serving stack.
 
 The paper's headline claim is a *latency* claim (emulation costs a 2-3x
 slowdown, §7), so the serving stack built on the emulation must report what
 a deployment actually buys: time-to-first-token (TTFT) and inter-token
 latency (ITL) under load -- not just aggregate swap/share counters.  This
-module provides the three pieces:
+module provides four pieces:
 
 * :class:`StepClock` -- decode-step-denominated time.  Every decode step
   (prefill token or batched decode step) ticks the clock once -- a fused
   multi-step run ticks ``tick(n)`` for its n steps in one call -- and idle
-  waits between trace arrivals tick it explicitly, so every latency number
+  waits between trace arrivals tick it explicitly, so every step latency
   is an exact integer count of decode steps: deterministic across reruns,
   platforms, mesh sizes and fused-run lengths, and directly comparable to
   the decode-step cost accounting the swap/spill workloads already use.
-  Wall-clock time would measure the host Python overhead of this
-  toy-scale model, not the policy.
 
 * :class:`RequestTrace` / :class:`Telemetry` -- per-request lifecycle
   tracing: arrival -> first admission -> first token -> completion, with
   queue wait, preemption count, swap/spill page hops and shared prompt
-  tokens per request.  Timestamps are taken when a token's logits are
-  *computed* (the step it could have been streamed), so a recompute replay
-  re-producing an already-produced token does not move its timestamp --
-  the recompute cost shows up where it belongs, in the following tokens'
-  gaps.  Aggregation is exact-percentile (:func:`percentile` matches
-  ``numpy.percentile``'s default linear interpolation) over completed
-  requests: p50/p95/p99 TTFT, ITL and queue wait.
+  tokens per request.  Every lifecycle point is stamped twice: on the
+  step clock (``arrival``, ``admit``, ``token_steps``, ``completion``)
+  and on the host's clock in nanoseconds of ``time.perf_counter_ns``
+  (``arrival_ns``, ``admit_ns``, ``token_ns``, ``completion_ns``).  Step
+  timestamps are taken when a token's logits are *computed* (the step it
+  could have been streamed), so a recompute replay re-producing an
+  already-produced token does not move its timestamp -- the recompute
+  cost shows up where it belongs, in the following tokens' gaps.  A
+  token's host timestamp is when the host saw it: the tokens of a fused
+  run share the time its token buffer reached the host.  Aggregation is
+  exact-percentile (:func:`percentile` matches ``numpy.percentile``'s
+  default linear interpolation) over completed requests: p50/p95/p99
+  TTFT, ITL and queue wait, in steps (``*_steps``) and in seconds
+  (``*_s``, :data:`WALL_KEYS`).
 
 * :class:`RollingMonitor` -- a sliding-window live monitor in the style of
   HomebrewNLP's ``wandblog.py`` early-stopping logger: a median over the
@@ -34,6 +39,15 @@ module provides the three pieces:
   *regression* flag when the median of the newest half-window exceeds
   ``regress_factor`` x the median of the oldest half-window (the
   distribution itself drifted, not one outlier).
+
+* :meth:`Telemetry.span` -- host spans at the boundaries of the engine's
+  and scheduler's hot loop.  Off by default, when a span is one shared
+  no-op context; between :meth:`Telemetry.start_spans` and
+  :meth:`Telemetry.stop_spans` each span is appended to
+  ``Telemetry.spans`` (name, start and end in ``perf_counter_ns``, the
+  enclosing span, the request's uid, counts) and wrapped in a
+  ``jax.profiler.TraceAnnotation`` named ``serve.<name>``, so that a
+  profiler trace holds it on the device planes' clock.
 
 The engine owns one :class:`Telemetry` (``ServeEngine.metrics``), exposes
 the summary via ``ServeEngine.telemetry()``, and folds it into the
@@ -44,6 +58,13 @@ from __future__ import annotations
 import collections
 import dataclasses
 import math
+import time
+
+import jax
+
+#: summary() keys counted in seconds of the host's clock; every other
+#: latency key counts decode steps
+WALL_KEYS = ("ttft_s", "itl_s", "queue_wait_s")
 
 
 def percentile(xs, q: float) -> float | None:
@@ -62,16 +83,23 @@ def percentile(xs, q: float) -> float | None:
     return s[lo] + (s[hi] - s[lo]) * (rank - lo)
 
 
-def _dist(xs) -> dict:
-    """Summary of a latency sample set: count, mean, exact percentiles."""
+def _dist(xs, digits: int = 3) -> dict:
+    """Summary of a latency sample set: count, mean, exact percentiles,
+    rounded to ``digits`` decimals."""
     if not xs:
         return {"n": 0}
     return {"n": len(xs),
-            "mean": round(sum(float(x) for x in xs) / len(xs), 3),
-            "p50": round(percentile(xs, 50), 3),
-            "p95": round(percentile(xs, 95), 3),
-            "p99": round(percentile(xs, 99), 3),
-            "max": round(max(float(x) for x in xs), 3)}
+            "mean": round(sum(float(x) for x in xs) / len(xs), digits),
+            "p50": round(percentile(xs, 50), digits),
+            "p95": round(percentile(xs, 95), digits),
+            "p99": round(percentile(xs, 99), digits),
+            "max": round(max(float(x) for x in xs), digits)}
+
+
+def step_summary(summary: dict) -> dict:
+    """``summary`` without its wall-clock keys: the step-denominated part,
+    which two runs of the same requests reproduce exactly."""
+    return {k: v for k, v in summary.items() if k not in WALL_KEYS}
 
 
 class StepClock:
@@ -90,9 +118,11 @@ class StepClock:
 
 @dataclasses.dataclass
 class RequestTrace:
-    """One request's lifecycle, every timestamp a StepClock reading."""
+    """One request's lifecycle: each timestamp a StepClock reading, and the
+    same points in ``perf_counter_ns`` (the ``*_ns`` fields)."""
     uid: int
     arrival: int
+    arrival_ns: int | None = None
     admit: int | None = None          # first admission (queue wait ends)
     completion: int | None = None
     #: production step of generated token i (the decode that computed its
@@ -110,6 +140,10 @@ class RequestTrace:
     #: the prompt found resident pages
     prefix_match_depth_pages: int = 0
     aborted: bool = False
+    admit_ns: int | None = None
+    completion_ns: int | None = None
+    #: when the host saw generated token i (parallel to token_steps)
+    token_ns: list[int] = dataclasses.field(default_factory=list)
 
     @property
     def queue_wait(self) -> int | None:
@@ -125,6 +159,25 @@ class RequestTrace:
     def itl_gaps(self) -> list[int]:
         """Decode-step gaps between consecutive generated tokens."""
         return [b - a for a, b in zip(self.token_steps, self.token_steps[1:])]
+
+    @property
+    def queue_wait_s(self) -> float | None:
+        if self.admit_ns is None or self.arrival_ns is None:
+            return None
+        return (self.admit_ns - self.arrival_ns) / 1e9
+
+    @property
+    def ttft_s(self) -> float | None:
+        """Arrival to the host seeing the first generated token, seconds."""
+        if not self.token_ns or self.arrival_ns is None:
+            return None
+        return (self.token_ns[0] - self.arrival_ns) / 1e9
+
+    def itl_gaps_s(self) -> list[float]:
+        """Seconds between the host seeing consecutive generated tokens
+        (0 between tokens of one fused run)."""
+        return [(b - a) / 1e9 for a, b in zip(self.token_ns,
+                                               self.token_ns[1:])]
 
 
 class RollingMonitor:
@@ -179,6 +232,62 @@ class RollingMonitor:
                 "regressed": self.regressed}
 
 
+class Span:
+    """One host span: ``name``, start and end (``perf_counter_ns``; the
+    end is None while it is open), the index in ``Telemetry.spans`` of
+    the span it opened inside (None at top level), the request's uid for
+    per-request spans, and what the span counted.  It is its own context
+    manager: entering appends it to ``Telemetry.spans`` and opens its
+    profiler annotation, leaving closes both; ``span[key] = n`` inside the
+    block sets one of its counts."""
+    __slots__ = ("name", "t0_ns", "t1_ns", "parent", "uid", "counts",
+                 "_tel", "_note")
+
+    def __init__(self, tel: "Telemetry", name: str, uid: int | None,
+                 counts: dict) -> None:
+        self.name, self.uid, self.counts = name, uid, counts
+        self.t0_ns = self.t1_ns = self.parent = self._note = None
+        self._tel = tel
+
+    def __enter__(self):
+        tel = self._tel
+        self.parent = tel._top
+        tel._top = len(tel.spans)
+        tel.spans.append(self)
+        self._note = jax.profiler.TraceAnnotation("serve." + self.name)
+        self._note.__enter__()
+        self.t0_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.t1_ns = time.perf_counter_ns()
+        self._note.__exit__(*exc)
+        self._tel._top = self.parent
+        self._tel = self._note = None
+        return False
+
+    def __setitem__(self, key, value) -> None:
+        self.counts[key] = value
+
+
+class _NoSpan:
+    """The context of every span while spans are off: records nothing and
+    drops what the block counts."""
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    def __setitem__(self, key, value) -> None:
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
 class Telemetry:
     """Per-request lifecycle recorder for one engine.
 
@@ -191,12 +300,36 @@ class Telemetry:
         self.clock = StepClock()
         self.traces: list[RequestTrace] = []
         self.monitor = RollingMonitor(window=monitor_window)
+        #: spans recorded while spans were on, in the order they opened
+        self.spans: list[Span] = []
+        self._top: int | None = None     # index of the innermost open span
+        self._spans_on = False
+
+    # -- host spans -----------------------------------------------------------
+    def start_spans(self) -> None:
+        """Record spans from now on (module docstring)."""
+        self._spans_on = True
+
+    def stop_spans(self) -> None:
+        """Stop recording spans; those recorded stay in ``spans``."""
+        self._spans_on = False
+
+    def span(self, name: str, uid: int | None = None, **counts):
+        """Context manager around one stretch of host work.  With spans off
+        it is a shared no-op; with spans on the stretch is recorded as a
+        :class:`Span` and annotated for the profiler as ``serve.<name>``.
+        ``counts`` start its counts; ``ctx[key] = n`` inside the block sets
+        one."""
+        if not self._spans_on:
+            return _NO_SPAN
+        return Span(self, name, uid, counts)
 
     def _trace(self, req) -> RequestTrace:
         tr = getattr(req, "_trace", None)
         if tr is None:
-            tr = req._trace = RequestTrace(uid=req.uid,
-                                           arrival=self.clock.now())
+            tr = req._trace = RequestTrace(
+                uid=req.uid, arrival=self.clock.now(),
+                arrival_ns=time.perf_counter_ns())
             self.traces.append(tr)
         return tr
 
@@ -212,6 +345,7 @@ class Telemetry:
         tr = self._trace(req)
         if tr.admit is None:
             tr.admit = self.clock.now()
+            tr.admit_ns = time.perf_counter_ns()
         tr.admissions += 1
         tr.resumes += int(resumed)
         tr.shared_tokens += shared_tokens
@@ -220,7 +354,8 @@ class Telemetry:
         tr.prefix_match_depth_pages = max(tr.prefix_match_depth_pages,
                                           int(match_depth_pages))
 
-    def on_token(self, req, index: int, at: int | None = None) -> None:
+    def on_token(self, req, index: int, at: int | None = None,
+                 seen_ns: int | None = None) -> None:
         """Generated token ``index`` was produced this step.  Re-production
         of an already-produced index (a recompute replay) keeps the first
         timestamp: the token could have been streamed then, and the replay
@@ -230,11 +365,15 @@ class Telemetry:
         run ticks the clock once for the whole run, then attributes each
         token to the step inside the run that actually computed its
         logits (run start + iteration + 1) -- the same integer the
-        stepwise path would have recorded."""
+        stepwise path would have recorded.  ``seen_ns`` is when the host
+        saw the token (default: now); a fused run reads the clock once and
+        passes it for all its tokens."""
         tr = self._trace(req)
         if index == len(tr.token_steps):
             tr.token_steps.append(self.clock.now() if at is None
                                   else int(at))
+            tr.token_ns.append(time.perf_counter_ns() if seen_ns is None
+                               else seen_ns)
             if index == 0:
                 self.monitor.push(tr.ttft)
 
@@ -248,7 +387,9 @@ class Telemetry:
         # the completing decode also computed a speculative next token that
         # will never be appended; drop it from the latency record
         del tr.token_steps[len(req.output):]
+        del tr.token_ns[len(req.output):]
         tr.completion = self.clock.now()
+        tr.completion_ns = time.perf_counter_ns()
 
     def on_abort(self, req) -> None:
         self._trace(req).aborted = True
@@ -273,12 +414,16 @@ class Telemetry:
 
     def summary(self) -> dict:
         """The SLO summary: exact TTFT/ITL/queue-wait percentiles over
-        completed requests (decode-step denominated) plus totals and the
-        rolling-monitor state."""
+        completed requests, in decode steps (``*_steps``) and in seconds
+        (``*_s``, to the microsecond), plus totals and the rolling-monitor
+        state."""
         done = [t for t in self.traces if t.completion is not None]
         ttfts = [t.ttft for t in done if t.ttft is not None]
         waits = [t.queue_wait for t in done if t.queue_wait is not None]
         gaps = [g for t in done for g in t.itl_gaps()]
+        ttfts_s = [t.ttft_s for t in done if t.ttft_s is not None]
+        waits_s = [t.queue_wait_s for t in done if t.queue_wait_s is not None]
+        gaps_s = [g for t in done for g in t.itl_gaps_s()]
         return {
             "steps": self.clock.now(),
             "arrived": len(self.traces),
@@ -294,5 +439,8 @@ class Telemetry:
             "ttft_steps": _dist(ttfts),
             "itl_steps": _dist(gaps),
             "queue_wait_steps": _dist(waits),
+            "ttft_s": _dist(ttfts_s, 6),
+            "itl_s": _dist(gaps_s, 6),
+            "queue_wait_s": _dist(waits_s, 6),
             "monitor": self.monitor.summary(),
         }

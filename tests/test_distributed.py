@@ -686,6 +686,7 @@ def test_serve_fused_kernel_token_identity_on_meshes(n_devices):
         from repro.models import Model, ModelConfig
         from repro.parallel import mesh_ctx
         from repro.serve import EngineConfig, Request, ServeEngine, Scheduler
+        from repro.serve.telemetry import step_summary
         n_dev = {n_devices}
         base = ModelConfig(name="t", family="dense", n_layers=2, d_model=64,
                            n_heads=8, n_kv_heads=2, head_dim=16, d_ff=128,
@@ -717,8 +718,8 @@ def test_serve_fused_kernel_token_identity_on_meshes(n_devices):
                 outs[impl] = {{r.uid: tuple(r.output) for r in done}}
                 mesh_ctx.clear_context()
             assert outs["fused"] == outs["composed"], (layout, outs)
-            assert stats["fused"]["telemetry"] == \\
-                stats["composed"]["telemetry"], layout
+            assert step_summary(stats["fused"]["telemetry"]) == \\
+                step_summary(stats["composed"]["telemetry"]), layout
             print("SERVE_KERNEL_OK", layout)
         print("ALL_SERVE_KERNEL_OK", n_dev)
     """, n_devices=max(n_devices, 2))
@@ -736,6 +737,7 @@ def test_fused_decode_token_identity_on_meshes(n_devices):
         from repro.models import Model, ModelConfig
         from repro.parallel import mesh_ctx
         from repro.serve import EngineConfig, Request, ServeEngine, Scheduler
+        from repro.serve.telemetry import step_summary
         n_dev = {n_devices}
         base = ModelConfig(name="t", family="dense", n_layers=2, d_model=64,
                            n_heads=8, n_kv_heads=2, head_dim=16, d_ff=128,
@@ -768,7 +770,8 @@ def test_fused_decode_token_identity_on_meshes(n_devices):
                 outs[fused] = {{r.uid: tuple(r.output) for r in done}}
                 mesh_ctx.clear_context()
             assert outs[8] == outs[1], (layout, outs)
-            assert stats[8]["telemetry"] == stats[1]["telemetry"], layout
+            assert step_summary(stats[8]["telemetry"]) == \\
+                step_summary(stats[1]["telemetry"]), layout
             assert stats[8]["dispatches"] < stats[1]["dispatches"], layout
         print("FUSED_MESH_OK", n_dev)
     """, n_devices=max(n_devices, 2))

@@ -14,6 +14,7 @@ import pytest
 
 from conftest import tiny_dense_cfg
 from repro.models import Model
+from repro.serve.telemetry import step_summary
 
 
 def _cfg(pool_pages=None, layout="pooled", page_slots=4):
@@ -53,7 +54,7 @@ def test_fused_matches_stepwise_pooled(rng):
     fused, st_f = _serve(prompts, max_fused_steps=8, **kw)
     step, st_s = _serve(prompts, max_fused_steps=1, **kw)
     assert fused == step
-    assert st_f["telemetry"] == st_s["telemetry"]
+    assert step_summary(st_f["telemetry"]) == step_summary(st_s["telemetry"])
     assert st_f["decode_steps"] == st_s["decode_steps"]
     assert st_f["dispatches"] < st_s["dispatches"]
 
@@ -67,7 +68,7 @@ def test_fused_matches_stepwise_reserved(rng):
     fused, st_f = _serve(prompts, max_fused_steps=8, **kw)
     step, st_s = _serve(prompts, max_fused_steps=1, **kw)
     assert fused == step
-    assert st_f["telemetry"] == st_s["telemetry"]
+    assert step_summary(st_f["telemetry"]) == step_summary(st_s["telemetry"])
     assert st_f["dispatches"] < st_s["dispatches"]
 
 
@@ -82,7 +83,7 @@ def test_fused_identity_under_swap_preemption(rng):
     fused, st_f = _serve(prompts, max_fused_steps=8, **kw)
     step, st_s = _serve(prompts, max_fused_steps=1, **kw)
     assert fused == step
-    assert st_f["telemetry"] == st_s["telemetry"]
+    assert step_summary(st_f["telemetry"]) == step_summary(st_s["telemetry"])
     assert st_f["swapped"] > 0                    # pressure actually hit
     assert st_f["swapped"] == st_s["swapped"]
     assert st_f["leaked_frames"] == st_s["leaked_frames"] == 0
@@ -99,7 +100,7 @@ def test_fused_identity_under_spill_pressure(rng):
     fused, st_f = _serve(prompts, max_fused_steps=8, **kw)
     step, st_s = _serve(prompts, max_fused_steps=1, **kw)
     assert fused == step
-    assert st_f["telemetry"] == st_s["telemetry"]
+    assert step_summary(st_f["telemetry"]) == step_summary(st_s["telemetry"])
     assert st_f["spill_out_pages"] > 0 and st_f["spill_in_pages"] > 0
     assert st_f["leaked_frames"] == st_f["leaked_spill_frames"] == 0
 
@@ -243,11 +244,12 @@ def test_fused_boundary_stats_match_stepwise(rng):
     assert st_f["prefetch_allocs"] > 0            # boundaries were staged
     assert st_f["prefetch_hits"] > 0
     keys = (set(st_f) | set(st_s)) - {"dispatches", "telemetry",
-                                      "score_cache_hits"}
+                                      "score_cache_hits",
+                                      "table_push_bytes"}
     diff = {k: (st_f.get(k), st_s.get(k)) for k in keys
             if st_f.get(k) != st_s.get(k)}
     assert not diff, diff
-    assert st_f["telemetry"] == st_s["telemetry"]
+    assert step_summary(st_f["telemetry"]) == step_summary(st_s["telemetry"])
     assert st_f["dispatches"] < st_s["dispatches"]
 
 
@@ -263,7 +265,7 @@ def test_fused_eos_early_exit(rng):
     step, st_s = _serve([prompt], max_fused_steps=1, eos_id=eos, **kw)
     assert fused == step
     assert fused[0] == base[0][:cut]
-    assert st_f["telemetry"] == st_s["telemetry"]
+    assert step_summary(st_f["telemetry"]) == step_summary(st_s["telemetry"])
     # prefill decodes each prompt token, then `cut` generation steps
     assert st_f["decode_steps"] == st_s["decode_steps"] == len(prompt) + cut
 

@@ -24,6 +24,7 @@ from conftest import tiny_dense_cfg
 from repro.emem_vm import BlockManager, FrameAllocator, PrefixTree
 from repro.emem_vm.allocator import OutOfFrames
 from repro.models import Model
+from repro.serve.telemetry import step_summary
 
 
 def _toks(*xs):
@@ -309,10 +310,12 @@ def test_engine_tree_linear_identity():
     out_t, tel_t, pool_t, stats_t = _shared_prefix_run("tree")
     out_l, tel_l, pool_l, stats_l = _shared_prefix_run("linear")
     assert out_t == out_l
-    assert tel_t == tel_l
+    assert step_summary(tel_t) == step_summary(tel_l)
     assert pool_t.pop("prefix_index") == "tree"
     assert pool_l.pop("prefix_index") == "linear"
     assert pool_t == pool_l
+    assert step_summary(stats_t.pop("telemetry")) == \
+        step_summary(stats_l.pop("telemetry"))
     assert stats_t == stats_l
 
 
@@ -383,7 +386,7 @@ def test_scheduler_score_cache_hits_and_identity():
     out_n, tel_n, hits_n = run(cache=False)
     assert hits_c > 0 and hits_n == 0
     assert out_c == out_n
-    assert tel_c == tel_n
+    assert step_summary(tel_c) == step_summary(tel_n)
 
 
 def test_score_cache_invalidated_by_epoch():

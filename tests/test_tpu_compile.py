@@ -103,7 +103,10 @@ def test_pooled_decode_step_compiles_for_v5e(one_chip, monkeypatch):
         model, params, _sds(one_chip, (B, 1), jnp.int32), cache,
         _sds(one_chip, (B,), jnp.int32),
         _sds(one_chip, (B,), jnp.bool_)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    # both kernels carry their stable names into the compiled step
+    assert "paged_gather_attend" in hlo and "paged_kv_write" in hlo
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
