@@ -39,9 +39,13 @@ Pages are laid out ``[n_pages, Hkv, slots, D]``: one KV head of one page
 is a contiguous ``[slots, D]`` slab, so the read kernel's block has legal
 TPU trailing dimensions, and the write kernel moves whole pages.
 
-Both take a ``meta`` scalar operand ``[sid, n_shards, kv_start]`` so one
-compiled kernel serves every shard of a shard_map body (sid/kv_start are
-traced axis indices).  Interpret mode keeps tier-1 running on CPU.
+Both take a ``meta`` scalar operand ``[sid, n_shards, kv_start, row_base]``
+so one compiled kernel serves every shard of a shard_map body (sid/kv_start
+are traced axis indices) and every layer of a layer scan: the page arrays
+may be the whole stacked pool viewed flat, ``[n_layers * np_loc, Hkv,
+slots, D]``, with ``row_base = layer * np_loc`` the layer's first row.  A
+row is clamped to the layer's ``np_loc`` rows before the base is added, so
+no visit leaves the layer.  Interpret mode keeps tier-1 running on CPU.
 """
 from __future__ import annotations
 
@@ -111,20 +115,23 @@ def paged_kv_write(k_new: jax.Array, v_new: jax.Array, k_pages: jax.Array,
                    v_pages: jax.Array, block_table: jax.Array,
                    lengths: jax.Array, frame_ro: jax.Array,
                    write_mask: jax.Array, meta: jax.Array, *,
-                   interpret: bool = False):
-    """k_new/v_new: [B, Hkv, D]; k/v_pages: [np_loc, Hkv, slots, D] (this
-    shard's pages); block_table: [B, max_lpages] GLOBAL frame ids;
-    meta: [sid, n_shards, kv_start].  Returns updated (k_pages, v_pages),
-    HBM-aliased with the inputs."""
+                   np_loc: int | None = None, interpret: bool = False):
+    """k_new/v_new: [B, Hkv, D]; k/v_pages: [rows, Hkv, slots, D] holding
+    this shard's ``np_loc`` pages of the written layer from row
+    ``meta[3]`` on (``np_loc`` defaults to all rows); block_table:
+    [B, max_lpages] GLOBAL frame ids; meta: [sid, n_shards, kv_start,
+    row_base].  Returns updated (k_pages, v_pages), HBM-aliased with the
+    inputs."""
     b, hkv, d = k_new.shape
-    np_loc, page_slots = k_pages.shape[0], k_pages.shape[2]
+    page_slots = k_pages.shape[2]
+    np_loc = k_pages.shape[0] if np_loc is None else np_loc
     max_lpages = block_table.shape[1]
 
     def page_map(bb, bt_ref, len_ref, fr_ref, wm_ref, meta_ref):
         row = _write_page_index(bb, bt_ref, len_ref, fr_ref, wm_ref,
                                 meta_ref, page_slots=page_slots,
                                 max_lpages=max_lpages, np_loc=np_loc)
-        return (row, 0, 0, 0)
+        return (row + meta_ref[3], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
@@ -216,18 +223,22 @@ def _gather_attend_kernel(bt_ref, len_ref, meta_ref, q_ref, k_ref, v_ref,
 def paged_gather_attend(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                         block_table: jax.Array, lengths: jax.Array,
                         meta: jax.Array, *, scale: float | None = None,
-                        window: int | None = None, interpret: bool = False):
+                        window: int | None = None,
+                        np_loc: int | None = None, interpret: bool = False):
     """q: [B, Hkv_loc, G, D] (this tp shard's query-head groups);
-    k/v_pages: [np_loc, Hkv, slots, D]; block_table: [B, max_lpages] GLOBAL
-    frame ids; meta: [sid, n_shards, kv_start] with kv_start the first KV
-    head of this tp shard.  Returns UNNORMALIZED partials
-    (acc [B, Hkv_loc, G, D] f32, m, l [B, Hkv_loc, G, 1] f32).
+    k/v_pages: [rows, Hkv, slots, D] holding the layer's ``np_loc`` local
+    pages from row ``meta[3]`` on (``np_loc`` defaults to all rows);
+    block_table: [B, max_lpages] GLOBAL frame ids; meta: [sid, n_shards,
+    kv_start, row_base] with kv_start the first KV head of this tp shard.
+    Returns UNNORMALIZED partials (acc [B, Hkv_loc, G, D] f32, m, l
+    [B, Hkv_loc, G, 1] f32).
 
     Each grid cell streams one head's ``[slots, D]`` slab of one page, the
     tile-aligned trailing block a TPU BlockSpec accepts (``slots`` a
     multiple of 8, ``D`` of 128, or the whole axis)."""
     b, hkv_loc, g, d = q.shape
-    np_loc, page_slots = k_pages.shape[0], k_pages.shape[2]
+    page_slots = k_pages.shape[2]
+    np_loc = k_pages.shape[0] if np_loc is None else np_loc
     max_lpages = block_table.shape[1]
     scale = (d ** -0.5) if scale is None else scale
 
@@ -236,7 +247,7 @@ def paged_gather_attend(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
         ns = meta_ref[1]
         ok = (f >= 0) & (f % ns == meta_ref[0])
         row = jnp.clip(jnp.where(ok, f // ns, 0), 0, np_loc - 1)
-        return (row, meta_ref[2] + h, 0, 0)
+        return (row + meta_ref[3], meta_ref[2] + h, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,

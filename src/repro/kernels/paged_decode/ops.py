@@ -62,20 +62,22 @@ def _identity_tables(b: int, max_pages: int):
 
 def paged_decode_shard(q, k_new, v_new, k_pages, v_pages, lengths, bt, fl,
                        fr, wm, *, sid, n_shards, head_start, group, window,
-                       max_pages, use_vm, impl, interpret=None):
+                       max_pages, use_vm, impl, layer=0, interpret=None):
     """One shard of the paged decode step.
 
     q: [B, Hl, hd] local query heads (whole KV-head groups for ``fused``);
-    k_new/v_new: [B, Hkv, hd]; k/v_pages: [np_loc, Hkv, slots, hd] local;
+    k_new/v_new: [B, Hkv, hd]; k/v_pages: one layer's local pages
+    [np_loc, Hkv, slots, hd], or the stacked pool [n_layers, np_loc, Hkv,
+    slots, hd] of which layer ``layer`` is written and read in place;
     bt/fl/fr: replicated VM tables (ignored when ``use_vm`` is False);
-    wm: [B] write mask; sid/head_start may be traced axis indices.
+    wm: [B] write mask; sid/head_start/layer may be traced.
     Returns (acc [B, Hl, hd] f32 unnormalized, m [B, Hl], l [B, Hl],
-    k_pages', v_pages')."""
+    k_pages', v_pages') with the pages in their input shape."""
     if impl == "composed":
         return _ref.paged_decode_shard(
             q, k_new, v_new, k_pages, v_pages, lengths, bt, fl, fr, wm,
             sid=sid, n_shards=n_shards, head_start=head_start, group=group,
-            window=window, max_pages=max_pages, use_vm=use_vm)
+            window=window, max_pages=max_pages, use_vm=use_vm, layer=layer)
 
     assert impl == "fused", impl
     interpret = (not _on_tpu()) if interpret is None else interpret
@@ -84,16 +86,26 @@ def paged_decode_shard(q, k_new, v_new, k_pages, v_pages, lengths, bt, fl,
         bt_use, fr_use = bt, fr
     else:
         bt_use, fr_use = _identity_tables(b, max_pages)
+    shape = k_pages.shape
+    np_loc = shape[-4]
+    row_base = 0
+    if k_pages.ndim == 5:
+        # merging the layer and page axes is a bitcast: the kernels alias
+        # the stacked pool itself and reach the layer through row_base
+        row_base = layer * np_loc
+        k_pages = k_pages.reshape((-1,) + shape[2:])
+        v_pages = v_pages.reshape((-1,) + shape[2:])
     kv_start = head_start // group
     meta = jnp.stack([jnp.asarray(sid, jnp.int32),
                       jnp.asarray(n_shards, jnp.int32),
-                      jnp.asarray(kv_start, jnp.int32)])
+                      jnp.asarray(kv_start, jnp.int32),
+                      jnp.asarray(row_base, jnp.int32)])
     k_pages, v_pages = _k.paged_kv_write(
         k_new, v_new, k_pages, v_pages, bt_use, lengths, fr_use, wm, meta,
-        interpret=interpret)
+        np_loc=np_loc, interpret=interpret)
     qg = q.reshape(b, hl // group, group, hd)
     acc, m, l = _k.paged_gather_attend(
         qg, k_pages, v_pages, bt_use, lengths, meta, window=window,
-        interpret=interpret)
+        np_loc=np_loc, interpret=interpret)
     return (acc.reshape(b, hl, hd), m.reshape(b, hl), l.reshape(b, hl),
-            k_pages, v_pages)
+            k_pages.reshape(shape), v_pages.reshape(shape))

@@ -91,11 +91,23 @@ def partial_attend(q, k_pages, v_pages, lengths, *, owner, lpage,
 
 def paged_decode_shard(q, k_new, v_new, k_pages, v_pages, lengths, bt, fl,
                        fr, wm, *, sid, n_shards, head_start, group, window,
-                       max_pages, use_vm):
+                       max_pages, use_vm, layer=0):
     """Composed per-shard decode step: masked WRITE scatter + partial
     attention over owned pages.  Same contract as the fused path in
     ``ops.paged_decode_shard``: returns (acc, m, l, k_pages, v_pages) with
-    ``acc`` unnormalized so the caller can merge across shards."""
+    ``acc`` unnormalized so the caller can merge across shards.  A stacked
+    pool ``[n_layers, np_loc, ...]`` has layer ``layer`` sliced out, run
+    and written back (copies the fused path avoids)."""
+    if k_pages.ndim == 5:
+        kl = jax.lax.dynamic_index_in_dim(k_pages, layer, keepdims=False)
+        vl = jax.lax.dynamic_index_in_dim(v_pages, layer, keepdims=False)
+        acc, m, l, kl, vl = paged_decode_shard(
+            q, k_new, v_new, kl, vl, lengths, bt, fl, fr, wm, sid=sid,
+            n_shards=n_shards, head_start=head_start, group=group,
+            window=window, max_pages=max_pages, use_vm=use_vm)
+        return (acc, m, l,
+                jax.lax.dynamic_update_index_in_dim(k_pages, kl, layer, 0),
+                jax.lax.dynamic_update_index_in_dim(v_pages, vl, layer, 0))
     b = q.shape[0]
     np_loc, slots = k_pages.shape[0], k_pages.shape[2]
     bt_ = bt if use_vm else None

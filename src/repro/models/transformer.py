@@ -304,13 +304,15 @@ def _mask_entry(new: dict, old: dict, write_mask: jax.Array) -> dict:
 
 def block_decode(cfg: ModelConfig, i: int, p: Params, x: jax.Array,
                  entry: dict, lengths: jax.Array, vm: dict | None = None,
-                 write_mask=None):
+                 write_mask=None, layer=0):
+    """``layer`` is the period index: a paged entry holds every period's
+    pages and is written and read in place at that layer's rows."""
     h = L.rms_norm(x, p["ln_mix"]["w"], cfg.rms_eps)
     if cfg.layer_kind(i) == "attn":
         if cfg.kv_layout in ("paged", "pooled"):
             from repro.parallel.paged_attention import paged_decode_block
             out, entry = paged_decode_block(cfg, p["attn"], h, entry, lengths,
-                                            vm, write_mask)
+                                            vm, write_mask, layer)
         else:
             old = entry
             out, k, v = L.decode_attention_block(
@@ -347,26 +349,40 @@ def decode_step(cfg: ModelConfig, params: Params, tokens: jax.Array,
     # (engine-managed, identical for every layer, no leading period axis)
     vm = cache.get("vm")
     blocks = {k: v for k, v in cache.items() if k.startswith("b")}
+    # paged entries ride the carry whole: each layer's attention writes and
+    # reads the stacked pool in place at its own rows, so no layer's pages
+    # are sliced out and stacked back.  Per-slot state (batch-layout k/v,
+    # SSM conv/ssd) is scanned over as before.
+    pools = {k: e for k, e in blocks.items() if "k_pages" in e}
+    scanned = {k: e for k, e in blocks.items() if k not in pools}
 
-    def period_step(h, scanees):
-        period_params, entries = scanees
-        new_entries = {}
+    def period_step(carry, scanees):
+        h, pools = carry
+        layer, period_params, entries = scanees
+        pools, new_entries = dict(pools), {}
         for i in range(cfg.layer_period):
-            h, new_entries[f"b{i}"] = block_decode(
-                cfg, i, period_params[f"b{i}"], h, entries[f"b{i}"], lengths,
-                vm, write_mask)
-        return maybe_constrain(h, ("dp", None, None)), new_entries
+            key = f"b{i}"
+            src, dst = ((pools, pools) if key in pools
+                        else (entries, new_entries))
+            h, dst[key] = block_decode(cfg, i, period_params[key], h,
+                                       src[key], lengths, vm, write_mask,
+                                       layer)
+        return (maybe_constrain(h, ("dp", None, None)), pools), new_entries
 
     stacked = {k: v for k, v in params.items() if k.startswith("b")}
     if cfg.unroll_layers:
         entries_list = []
         for j in range(cfg.n_periods):
-            x, e = period_step(x, (jax.tree.map(lambda v: v[j], stacked),
-                                   jax.tree.map(lambda v: v[j], blocks)))
+            (x, pools), e = period_step(
+                (x, pools), (j, jax.tree.map(lambda v: v[j], stacked),
+                             jax.tree.map(lambda v: v[j], scanned)))
             entries_list.append(e)
         cache = jax.tree.map(lambda *xs: jnp.stack(xs), *entries_list)
     else:
-        x, cache = jax.lax.scan(period_step, x, (stacked, blocks))
+        (x, pools), cache = jax.lax.scan(
+            period_step, (x, pools),
+            (jnp.arange(cfg.n_periods), stacked, scanned))
+    cache = {**cache, **pools}
     if vm is not None:
         cache = {**cache, "vm": vm}
     x = L.rms_norm(x, params["ln_f"]["w"], cfg.rms_eps)

@@ -59,9 +59,12 @@ def _flat_axis_index(axes: tuple[str, ...]) -> jax.Array:
 
 def paged_decode_attention(cfg: ModelConfig, q, k_new, v_new, k_pages,
                            v_pages, lengths, vm: dict | None = None,
-                           write_mask=None):
+                           write_mask=None, layer=0):
     """q: [B, H, hd]; k_new/v_new: [B, Hkv, hd] (rope'd at position len-1);
-    k/v_pages: [n_pages, Hkv, slots, hd] global.  Returns (out, pages').
+    k/v_pages: one layer's global pages [n_pages, Hkv, slots, hd], or the
+    stacked pool [n_layers, n_pages, Hkv, slots, hd] of which layer
+    ``layer`` (may be traced) is written and read in place.  Returns
+    (out, pages') with the pages in their input shape.
 
     ``vm`` is the BlockManager translation state documented in the module
     docstring; without it the fixed arithmetic mapping applies.
@@ -72,7 +75,7 @@ def paged_decode_attention(cfg: ModelConfig, q, k_new, v_new, k_pages,
     latest position overwritten with pad-token K/V."""
     ctx = mesh_ctx.get_context()
     b, h, hd = q.shape
-    n_pages = k_pages.shape[0]
+    n_pages = k_pages.shape[-4]
     max_pages = n_pages // b
     group = cfg.n_heads // cfg.n_kv_heads
     if write_mask is None:
@@ -92,7 +95,7 @@ def paged_decode_attention(cfg: ModelConfig, q, k_new, v_new, k_pages,
             q, k_new, v_new, k_pages, v_pages, lengths, bt, fl, fr,
             write_mask, sid=0, n_shards=1, head_start=0, group=group,
             window=cfg.window, max_pages=max_pages, use_vm=pooled,
-            impl=impl)
+            impl=impl, layer=layer)
         out = (acc / jnp.where(l == 0.0, 1.0, l)[..., None]).astype(q.dtype)
         return out, kp, vp
 
@@ -104,13 +107,15 @@ def paged_decode_attention(cfg: ModelConfig, q, k_new, v_new, k_pages,
     tp_axis = ctx.tp_axis
     impl = pd_ops.resolve_impl(cfg.paged_kernel, hl, group)
 
-    def body(q_l, k_new_l, v_new_l, kp_l, vp_l, len_l, bt, fl, fr, wm):
+    def body(q_l, k_new_l, v_new_l, kp_l, vp_l, len_l, bt, fl, fr, wm,
+             layer):
         sid = _flat_axis_index(kv_axes)
         tp_idx = jax.lax.axis_index(tp_axis)
         acc, m, l, kp_l, vp_l = pd_ops.paged_decode_shard(
             q_l, k_new_l, v_new_l, kp_l, vp_l, len_l, bt, fl, fr, wm,
             sid=sid, n_shards=n_shards, head_start=tp_idx * hl, group=group,
-            window=cfg.window, max_pages=max_pages, use_vm=pooled, impl=impl)
+            window=cfg.window, max_pages=max_pages, use_vm=pooled, impl=impl,
+            layer=layer)
         # merge partials across the emulated-memory shards
         m_glob = jax.lax.pmax(m, kv_axes)
         w = jnp.exp(m - m_glob)
@@ -119,30 +124,34 @@ def paged_decode_attention(cfg: ModelConfig, q, k_new, v_new, k_pages,
         out = (num / jnp.where(den == 0.0, 1.0, den)[..., None]).astype(q_l.dtype)
         return out, kp_l, vp_l
 
-    kv_spec = P(kv_axes if len(kv_axes) > 1 else kv_axes[0])
+    # the page axis is sharded; a stacked pool's layer axis is not
+    kv_spec = P(*(None,) * (k_pages.ndim - 4),
+                kv_axes if len(kv_axes) > 1 else kv_axes[0])
     fn = jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(P(None, tp_axis, None), P(), P(), kv_spec, kv_spec, P(),
-                  P(), P(), P(), P()),
+                  P(), P(), P(), P(), P()),
         out_specs=(P(None, tp_axis, None), kv_spec, kv_spec),
         check_vma=False)
     return fn(q, k_new, v_new, k_pages, v_pages, lengths, bt, fl, fr,
-              write_mask)
+              write_mask, jnp.asarray(layer, jnp.int32))
 
 
 def paged_decode_block(cfg: ModelConfig, p_attn: dict, h: jax.Array,
                        entry: dict, lengths: jax.Array,
-                       vm: dict | None = None, write_mask=None):
+                       vm: dict | None = None, write_mask=None, layer=0):
     """Attention sub-block for decode with the paged/pooled KV layout.
 
-    h: [B, 1, d] (already normed).  Returns (out [B, 1, d], new entry)."""
+    h: [B, 1, d] (already normed); ``entry`` holds the stacked pool of
+    every period, of which layer ``layer`` is used in place.  Returns
+    (out [B, 1, d], new entry)."""
     from repro.models import layers as L
     b = h.shape[0]
     positions = (lengths - 1)[:, None]
     q, k_new, v_new = L._project_qkv(cfg, p_attn, h, positions)
     out, kp, vp = paged_decode_attention(
         cfg, q[:, :, 0], k_new[:, :, 0], v_new[:, :, 0],
-        entry["k_pages"], entry["v_pages"], lengths, vm, write_mask)
+        entry["k_pages"], entry["v_pages"], lengths, vm, write_mask, layer)
     out = out.reshape(b, 1, cfg.n_heads * cfg.hd) @ p_attn["wo"]
     return out, {"k_pages": kp, "v_pages": vp}
 

@@ -297,54 +297,73 @@ def test_vm_valid_bit_swap_matches_oracle_on_meshes():
     assert "ALL_SWAP_OK" in out
 
 
-def test_pooled_decode_matches_batch_on_mesh():
-    """kv_layout="pooled" with scattered frame assignments matches the
-    batch-layout reference on a (4 kv) x (2 tp) mesh."""
-    out = run_with_devices("""
+@pytest.mark.parametrize("impl", ["composed", "fused"])
+def test_pooled_decode_matches_batch_on_mesh(impl):
+    """kv_layout="pooled" with scattered frame assignments on a (4 kv) x
+    (2 tp) mesh -- the stacked pool carried through the layer scan, its
+    page axis sharded, P(None, kv_axes) -- matches the same decode on one
+    device, pages and logits, and the batch-layout reference."""
+    out = run_with_devices(f"""
         import dataclasses
+        from repro.emem_vm.layout import frame_rows
         from repro.models import Model, ModelConfig
         from repro.parallel import mesh_ctx
         cfg = ModelConfig(name="t", family="dense", n_layers=2, d_model=64,
                           n_heads=8, n_kv_heads=2, head_dim=16, d_ff=128,
                           vocab_size=128, kv_layout="pooled", kv_page_slots=4,
                           kv_pool_pages=16, param_dtype="float32",
-                          compute_dtype="float32")
-        mesh = make_mesh((4, 2), ("data", "model"))
-        mesh_ctx.set_context(mesh, batch_axes=("data",), tp_axis="model",
-                             kv_axes=("data",))
+                          compute_dtype="float32", paged_kernel="{impl}")
         model = Model(cfg)
         params = model.init(jax.random.key(0))
         rng = np.random.default_rng(0)
         B, S = 2, 8
         toks = jnp.asarray(rng.integers(0, 128, (B, S)))
-        cache = model.init_cache(B, 16)
-        # host-managed tables with deliberately scattered frames
-        bt = np.full((B, 4), -1, np.int32)
-        fl = np.zeros(16, np.int32)
-        fr = np.zeros(16, bool)
-        alloc = iter([5, 2, 11, 7, 3, 13, 1, 9])
-        lengths = jnp.zeros((B,), jnp.int32)
-        for t in range(S):
-            lengths = lengths + 1
-            for b in range(B):
-                lp = t // 4
-                if bt[b, lp] < 0:
-                    f = next(alloc); bt[b, lp] = f; fl[f] = lp
-            cache["vm"] = {"block_table": jnp.array(bt),
-                           "frame_lpage": jnp.array(fl),
-                           "frame_ro": jnp.array(fr)}
-            logits_p, cache = model.decode_step(params, toks[:, t:t+1],
-                                                cache, lengths)
-            jax.block_until_ready(logits_p)
-        mesh_ctx.clear_context()
+
+        def decode(mesh):
+            if mesh is not None:
+                mesh_ctx.set_context(mesh, batch_axes=("data",),
+                                     tp_axis="model", kv_axes=("data",))
+            cache = model.init_cache(B, 16)
+            # host-managed tables with deliberately scattered frames
+            bt = np.full((B, 4), -1, np.int32)
+            fl = np.zeros(16, np.int32)
+            fr = np.zeros(16, bool)
+            alloc = iter([5, 2, 11, 7, 3, 13, 1, 9])
+            lengths = jnp.zeros((B,), jnp.int32)
+            for t in range(S):
+                lengths = lengths + 1
+                for b in range(B):
+                    lp = t // 4
+                    if bt[b, lp] < 0:
+                        f = next(alloc); bt[b, lp] = f; fl[f] = lp
+                cache["vm"] = {{"block_table": jnp.array(bt),
+                               "frame_lpage": jnp.array(fl),
+                               "frame_ro": jnp.array(fr)}}
+                logits, cache = model.decode_step(params, toks[:, t:t+1],
+                                                  cache, lengths)
+                jax.block_until_ready(logits)
+            mesh_ctx.clear_context()
+            return logits, cache["b0"]
+
+        logits_p, pages_p = decode(make_mesh((4, 2), ("data", "model")))
+        assert pages_p["k_pages"].shape == (2, 16, 2, 4, 16)
+        logits_1, pages_1 = decode(None)
+        # frame f sits at row f of one device's pool, and on shard f % 4
+        rows = frame_rows(np.arange(16), 16, 4)
+        for key in ("k_pages", "v_pages"):
+            np.testing.assert_allclose(np.asarray(pages_p[key])[:, rows],
+                                       np.asarray(pages_1[key]),
+                                       rtol=1e-5, atol=1e-5)
+        err1 = float(jnp.max(jnp.abs(logits_p[:, :128] - logits_1[:, :128])))
+        assert err1 < 1e-4, err1
         cfg_b = dataclasses.replace(cfg, kv_layout="batch")
         mb = Model(cfg_b)
-        _, cache_b = mb.prefill(params, {"tokens": toks[:, :-1]}, max_len=16)
+        _, cache_b = mb.prefill(params, {{"tokens": toks[:, :-1]}}, max_len=16)
         logits_b, _ = mb.decode_step(params, toks[:, -1:], cache_b,
                                      jnp.full((B,), S, jnp.int32))
         err = float(jnp.max(jnp.abs(logits_p[:, :128] - logits_b[:, :128])))
         assert err < 1e-3, err
-        print("POOLED_MESH_OK", err)
+        print("POOLED_MESH_OK", err1, err)
     """)
     assert "POOLED_MESH_OK" in out
 

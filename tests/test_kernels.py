@@ -144,25 +144,33 @@ def _mk_vm(rng, b, max_lpages, page_slots, lengths, shared_page0=False):
 
 def _run_shard(rng, impl, *, b=3, max_lpages=4, page_slots=8, hkv=2, group=2,
                window=None, lengths=(25, 9, 17), shared_page0=False,
-               write_mask=None, use_vm=True):
+               write_mask=None, use_vm=True, layers=None, layer=0, sid=0,
+               n_shards=1, inputs=None):
+    """One shard's step.  ``layers`` makes the pages a stacked pool
+    [layers, np_loc, ...] of which ``layer`` is used; ``inputs``, a dict,
+    receives the pages as given."""
     hl, hd = hkv * group, 16
     n_frames = b * max_lpages
     lengths = np.asarray(lengths, np.int32)
     q = jnp.asarray(rng.normal(size=(b, hl, hd)).astype(np.float32))
     k_new = jnp.asarray(rng.normal(size=(b, hkv, hd)).astype(np.float32))
     v_new = jnp.asarray(rng.normal(size=(b, hkv, hd)).astype(np.float32))
-    kp = jnp.asarray(rng.normal(size=(n_frames, hkv, page_slots, hd))
-                     .astype(np.float32))
-    vp = jnp.asarray(rng.normal(size=(n_frames, hkv, page_slots, hd))
-                     .astype(np.float32))
+    shape = (n_frames // n_shards, hkv, page_slots, hd)
+    if layers is not None:
+        shape = (layers,) + shape
+    kp = jnp.asarray(rng.normal(size=shape).astype(np.float32))
+    vp = jnp.asarray(rng.normal(size=shape).astype(np.float32))
+    if inputs is not None:
+        inputs.update(k_pages=kp, v_pages=vp)
     bt, fl, fr = _mk_vm(rng, b, max_lpages, page_slots, lengths,
                         shared_page0=shared_page0)
     wm = jnp.asarray(np.ones(b, bool) if write_mask is None
                      else np.asarray(write_mask, bool))
     return pd_ops.paged_decode_shard(
         q, k_new, v_new, kp, vp, jnp.asarray(lengths), bt, fl, fr, wm,
-        sid=0, n_shards=1, head_start=0, group=group, window=window,
-        max_pages=max_lpages, use_vm=use_vm, impl=impl, interpret=True)
+        sid=sid, n_shards=n_shards, head_start=0, group=group, window=window,
+        max_pages=max_lpages, use_vm=use_vm, impl=impl, layer=layer,
+        interpret=True)
 
 
 def _assert_shard_match(fused, composed):
@@ -179,19 +187,35 @@ def _assert_shard_match(fused, composed):
                                rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("layer", [None, 0, 1, 3])
 @pytest.mark.parametrize("window", [None, 8])
 @pytest.mark.parametrize("lengths", [(25, 9, 17), (1, 32, 8), (31, 2, 24)])
-def test_paged_decode_fused_matches_composed(rng, window, lengths):
+def test_paged_decode_fused_matches_composed(rng, window, lengths, layer):
     """The fused kernels walking cache['vm'] inside the grid reproduce the
     composed host-side-translation oracle over ragged lengths, scrambled
     frame assignments and sliding windows: same written pages (byte-equal),
-    same running max (exact), same softmax statistics."""
+    same running max (exact), same softmax statistics.
+
+    With ``layer`` set the pages are a stacked pool of four layers, used in
+    place at that layer's rows, and the shard is the second of two with
+    page 0 shared read-only and one write masked off: not-my-shard visits,
+    clamped rows and dropped writes must all stay inside the layer, so
+    every other layer comes out byte-unchanged."""
+    kw = dict(window=window, lengths=lengths)
+    if layer is not None:
+        kw.update(layers=4, layer=layer, sid=1, n_shards=2,
+                  shared_page0=True, write_mask=(True, True, False))
     seed = int(rng.integers(1 << 31))
-    fused = _run_shard(np.random.default_rng(seed), "fused",
-                       window=window, lengths=lengths)
-    composed = _run_shard(np.random.default_rng(seed), "composed",
-                          window=window, lengths=lengths)
+    given = {}
+    fused = _run_shard(np.random.default_rng(seed), "fused", inputs=given,
+                       **kw)
+    composed = _run_shard(np.random.default_rng(seed), "composed", **kw)
     _assert_shard_match(fused, composed)
+    if layer is not None:
+        others = np.arange(4) != layer
+        for out, pages in zip(fused[3:], given.values()):
+            np.testing.assert_array_equal(np.asarray(out)[others],
+                                          np.asarray(pages)[others])
 
 
 @pytest.mark.parametrize("window", [None, 40])

@@ -196,15 +196,36 @@ def _pooled_cfg(pool_pages=None, layout="pooled"):
                           kv_pool_pages=pool_pages)
 
 
-def test_serve_pooled_matches_fixed_paged(rng):
-    """kv_layout="pooled" is token-identical to the fixed paged layout."""
+def _hybrid_cfg(**kw):
+    """Two-layer period: one attention layer, then one mamba layer."""
+    return ModelConfig(
+        name="t-hyb", family="hybrid", n_layers=4, d_model=64, n_heads=4,
+        n_kv_heads=2, head_dim=16, d_ff=128, vocab_size=64, attn_period=2,
+        attn_offset=0, ssm_state=8, ssm_head_dim=16, ssm_groups=1,
+        ssm_conv=4, ssm_expand=2, ssd_chunk=8, param_dtype="float32",
+        compute_dtype="float32", attn_chunk_q=16, attn_chunk_k=16,
+        kv_page_slots=4, **kw)
+
+
+@pytest.mark.parametrize("variant", ["scan", "unroll_layers", "hybrid"])
+def test_serve_pooled_matches_fixed_paged(rng, variant):
+    """kv_layout="pooled" is token-identical to the fixed paged layout and
+    to the batch layout, whether the layer scan carries the page pool
+    (scan), the layers are unrolled, or a period mixes a carried attention
+    pool with scanned SSM state (hybrid)."""
     from repro.serve import EngineConfig, Request, ServeEngine, Scheduler
     prompts = [rng.integers(0, 64, int(rng.integers(2, 7))).astype(np.int32)
                for _ in range(5)]
     outs = {}
-    for layout in ("paged", "pooled"):
-        cfg = _pooled_cfg(pool_pages=16 if layout == "pooled" else None,
-                          layout=layout)
+    for layout in ("batch", "paged", "pooled"):
+        kw = dict(kv_layout=layout,
+                  kv_pool_pages=16 if layout == "pooled" else None)
+        if variant == "hybrid":
+            cfg = _hybrid_cfg(**kw)
+        else:
+            cfg = dataclasses.replace(
+                _pooled_cfg(), unroll_layers=variant == "unroll_layers",
+                **kw)
         model = Model(cfg)
         params = model.init(jax.random.key(0))
         engine = ServeEngine(model, params, EngineConfig(slots=2, max_len=32))
@@ -215,7 +236,7 @@ def test_serve_pooled_matches_fixed_paged(rng):
         outs[layout] = {r.uid: tuple(r.output) for r in done}
         if layout == "pooled":
             assert engine.pool_stats()["used"] == 0   # all frames released
-    assert outs["paged"] == outs["pooled"]
+    assert outs["batch"] == outs["paged"] == outs["pooled"]
 
 
 def test_serve_pooled_oversubscribes_fixed_reservation(rng):
