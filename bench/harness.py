@@ -10,7 +10,9 @@ the profiler's trace to the cell's metrics.
 Everything that belongs to one configuration, one traffic mix or one
 per-layer metric is a file of its own, found by the name in
 ``BENCHMARK.json``: ``bench/configs/<config>.json``,
-``bench/traffic/<traffic>.json``, ``bench/metrics/<metric>.py``.
+``bench/traffic/<traffic>.json``, ``bench/metrics/<metric>.py``; and what
+belongs to one architecture is ``bench/archs/<model_type>.py``, found by
+the configuration file's ``model_type``.
 
 Host times are the benchmark's own spans around ``ServeEngine.admit`` and
 ``ServeEngine.step``; both end in a host read of sampled tokens, so they
@@ -19,6 +21,7 @@ sampled it returns.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 import gc
@@ -108,50 +111,12 @@ def chip_cell(workload: str, log=sys.stderr) -> Cell | None:
 
 # -- the program ------------------------------------------------------------
 
-def model_config(c: dict):
-    """The program's ``ModelConfig`` for configuration file ``c``: the
-    registry entry of ``c["arch"]`` at the sizes the file gives."""
-    import dataclasses as dc
-
-    from repro.configs import get_config
-    k, sv = work.dims(c), c["serving"]
-    base = get_config(c["arch"])
-    if base.tie_embeddings != k["tied"] or base.qk_norm != k["qk_norm"]:
-        raise ValueError(f"{c['arch']}: the registry's tie/qk_norm differ "
-                         f"from the configuration file")
-    dtype = c["torch_dtype"]
-    return dc.replace(
-        base, n_layers=k["L"], d_model=k["d"], n_heads=k["H"],
-        n_kv_heads=k["Hkv"], head_dim=k["hd"], d_ff=k["ff"],
-        vocab_size=k["V"], rope_theta=float(c["rope_theta"]),
-        rms_eps=float(c["rms_norm_eps"]), param_dtype=dtype,
-        compute_dtype=dtype, kv_layout=sv["kv_layout"],
-        paged_kernel=sv["paged_kernel"], kv_page_slots=sv["page_slots"])
-
-
-def program_params(w: dict, cfg) -> dict:
-    """The benchmark's weights in the program's parameter tree (the
-    embedding padded with zero rows to the program's padded vocabulary)."""
-    import jax.numpy as jnp
-    emb = w["embed"]
-    pad = cfg.vocab_padded - emb.shape[0]
-    if pad:
-        emb = jnp.pad(emb, ((0, pad), (0, 0)))
-    attn = {n: w[n] for n in ("wq", "wk", "wv", "wo", "q_norm", "k_norm")
-            if n in w}
-    p = {"embed": {"tok": emb}, "ln_f": {"w": w["final_norm"]},
-         "b0": {"ln_mix": {"w": w["attn_norm"]}, "attn": attn,
-                "ln_mlp": {"w": w["mlp_norm"]},
-                "mlp": {n: w[n] for n in ("w_gate", "w_up", "w_down")}}}
-    if "head" in w:
-        p["embed"]["head"] = jnp.pad(w["head"], ((0, 0), (0, pad)))
-    return p
-
-
 def build_engine(c: dict, w: dict):
     """(engine, scheduler) as ``repro.launch.serve.build`` and ``run``
     make them, with the benchmark's weights: the entry point's parsed
-    defaults, the KV mesh context for ``kv_shards``, the engine config."""
+    defaults, the KV mesh context for ``kv_shards``, the engine config.
+    The model's config and parameter tree come from the configuration's
+    architecture module (``bench/archs/<model_type>.py``)."""
     import jax
 
     from repro.launch import serve
@@ -168,14 +133,15 @@ def build_engine(c: dict, w: dict):
         "--max-len", str(sv["max_len"]), "--kv-shards", str(sv["kv_shards"]),
         "--retain-frames", str(sv["retain_frames"]),
         "--max-fused-steps", str(sv["max_fused_steps"])])
-    cfg = model_config(c)
+    arch = work.arch(c)
+    cfg = arch.model_config(c)
     if args.kv_shards > 1:
         mesh_ctx.set_context(make_host_mesh(args.kv_shards),
                              kv_axes=("data",))
     else:
         mesh_ctx.clear_context()
     model = Model(cfg)
-    params = program_params(w, cfg)
+    params = arch.program_params(w, cfg)
     want = jax.tree.map(lambda s: (s.shape, s.dtype), model.shapes())
     got = jax.tree.map(lambda a: (a.shape, a.dtype), params)
     if want != got:
@@ -486,14 +452,15 @@ COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 @contextlib.contextmanager
 def _compile_counter():
-    """A list that grows by one for every program JAX traces or compiles
-    while the context is open."""
+    """A list that grows by the name of every program JAX traces or
+    compiles while the context is open."""
     import jax
     events: list = []
 
-    def listener(event: str, duration: float, **_) -> None:
+    def listener(event: str, duration: float, fun_name: str = "?",
+                 **_) -> None:
         if event in (TRACE_EVENT, COMPILE_EVENT):
-            events.append(event)
+            events.append(fun_name)
 
     jax.monitoring.register_event_duration_secs_listener(listener)
     try:
@@ -556,6 +523,24 @@ def _check_sample(drv: Driver, rec: Recorder, t0: float, t1: float,
     return out
 
 
+def _crossing_warmup(c: dict, mix: dict, seed: int) -> list:
+    """Closed loop: ``max_fused_steps`` requests served together before the
+    clients start.  Their prompts differ by one token each and their outputs
+    end just past the first page boundary, one step apart, so a page
+    boundary falls at every step of a fused run and the requests complete on
+    consecutive steps with none admitted after them.  A fused run then
+    follows a table push (fresh tables, committed lengths): an input mix
+    the program compiles for, which the window's long outputs meet and the
+    clients' first minute of short contexts does not."""
+    sv = c["serving"]
+    n = min(sv["max_fused_steps"], sv["slots"])
+    p0 = mix["prompt"]["min"]
+    rng = np.random.default_rng([seed, 4])
+    return [traffic.Item(0.0, rng.integers(0, c["vocab_size"], p0 + j)
+                         .astype(np.int32), sv["page_slots"] - p0 + n + j)
+            for j in range(n)]
+
+
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
              process_start: float, peak_table: dict | None = None,
              log=sys.stderr, control: bool = False,
@@ -580,9 +565,16 @@ def _run(cell, seed, seconds, trace, process_start, peak_table, say,
     c, mix = cell.config, cell.mix
     devs = jax.devices()
     dev = devs[0]
+    marks = [(None, process_start, 0)]
+
+    def mark(phase: str) -> None:
+        marks.append((phase, time.perf_counter(), len(compiles)))
+
+    mark("start-up")
     w = W.make(c, seed, c["torch_dtype"])
     engine, sched = build_engine(c, w)
     del w
+    mark("weights and engine")
     rec = Recorder(engine, annotate=trace)
     drv = Driver(engine, sched, rec)
     sv = c["serving"]
@@ -599,10 +591,12 @@ def _run(cell, seed, seconds, trace, process_start, peak_table, say,
         drv.send(traffic.Item(0.0, np.append(base, last).astype(np.int32),
                               warm_new), time.perf_counter())
     drv.drain()
+    mark("first requests")
     if sch.loop == "open":
         for item in sch.warm:
             drv.send(item, time.perf_counter())
         drv.drain()
+        mark("shared prompts")
         drv.reqs.clear()
         # the lead: the requests due before the window, served as they
         # fall due, so that the window opens on a loaded engine
@@ -610,13 +604,19 @@ def _run(cell, seed, seconds, trace, process_start, peak_table, say,
         drv.start_open(sch.items, t_open)
         drv.open_loop(t_open)
     else:
+        for item in _crossing_warmup(c, mix, seed):
+            drv.send(item, time.perf_counter())
+        drv.drain()
+        mark("page crossings")
         drv.start_clients(sch.items)
         drv.closed_loop(time.perf_counter() + mix["warmup_s"])
+        mark("clients' warm-up")
         quiet = 0
         while quiet < QUIET_TICKS:
             n = len(compiles)
             drv.closed_tick()
             quiet = quiet + 1 if len(compiles) == n else 0
+    mark("lead" if sch.loop == "open" else "quiet ticks")
     n_compiles = len(compiles)
     counters0 = dict(engine.counters)
 
@@ -627,6 +627,12 @@ def _run(cell, seed, seconds, trace, process_start, peak_table, say,
     say(f"window {window_s:.3f} s after {setup_s:.3f} s of set-up; "
         f"compiles in set-up {n_compiles}, in the window "
         f"{len(compiles) - n_compiles}")
+    say("set-up by phase (seconds, programs traced): " + ", ".join(
+        f"{name} {t - t_prev:.3f} {n - n_prev}"
+        for (_, t_prev, n_prev), (name, t, n) in zip(marks, marks[1:])))
+    traced = collections.Counter(compiles[n_compiles:])
+    if traced:
+        say(f"programs traced in the window: {traced.most_common(10)}")
 
     # end-to-end numbers.  The window's requests are those due from its
     # nominal opening (open loop: the end of the lead; the window itself

@@ -1,7 +1,8 @@
 """Kernels (``kernels/paged_decode/kernel.py`` ``paged_gather_attend``):
 roofline share of the kernel's trace events, summed over the chips.  The
-work is that of the actual context lengths in every layer of the traced
-decode iterations (``bench/work.py``), not of the pages the kernel walks.
+work is that of the actual context lengths in every layer that holds
+paged K/V (``paged_layers``), in the traced decode iterations
+(``bench/work.py``), not of the pages the kernel walks.
 
 The kernel has no name of its own in the compiled step (a ``closed_call``
 custom call); it is the one that returns the three float32 partials
@@ -15,7 +16,7 @@ def read(run):
     if run.trace is None or run.peak is None:
         return None
     device_s = run.trace.kernel_s(KERNEL) * run.n_chips
-    layers = run.config["num_hidden_layers"]
+    layers = work.paged_layers(run.config)
     flops = nbytes = 0
     for s in run.traced_spans:
         for ctxs in s.ctxs:

@@ -1,14 +1,13 @@
 """Plain reference of the served model, and the comparison that decides
 ``correct``.
 
-The reference is the published architecture written out in ``jax.numpy``:
-a decoder of RMS-normed pre-norm blocks, grouped-query causal attention
-with rotary positions (split halves, ``rope_theta``) and, for Qwen3, an
-RMS norm over each query and key head before the rotation; a SwiGLU MLP;
-a final RMS norm and the (tied) unembedding.  It runs one whole sequence
-at a time, in float32 at the highest matmul precision, over the weights
-``bench.weights`` makes from the seed (the bf16 values the model is served
-with, widened).  It imports nothing of the program.
+The reference is the published architecture written out in ``jax.numpy``,
+in the configuration's architecture module (``forward`` in
+``bench/archs/<model_type>.py``), from the numerics here (``_mm``,
+``_rms``, ``_rope``).  It runs one whole sequence at a time, in float32 at
+the highest matmul precision, over the weights ``bench.weights`` makes
+from the seed (the bf16 values the model is served with, widened).  It
+imports nothing of the program.
 
 The compared number is the widest gap, over every served token of a
 sample of finished requests, by which the served token's reference logit
@@ -22,14 +21,12 @@ the float8 reference puts first.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from bench import weights as W
-from bench.work import dims
+from bench import work
 
 HIGHEST = jax.lax.Precision.HIGHEST
 F8 = jnp.float8_e4m3fn
@@ -68,49 +65,6 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-@functools.partial(jax.jit, static_argnums=(0, 3))
-def _forward(k: tuple, w: dict, tokens, mode: str):
-    """Logits [S, V] of one token sequence (float32)."""
-    L, d, H, Hkv, hd, qk_norm, tied, eps, theta = k
-    s = tokens.shape[0]
-    pos = jnp.arange(s)
-    causal = pos[:, None] >= pos[None, :]
-    g = H // Hkv
-    x = w["embed"][tokens]
-
-    def layer(x, p):
-        h = _rms(x, p["attn_norm"], eps)
-        q = _mm(h, p["wq"], mode).reshape(s, H, hd).transpose(1, 0, 2)
-        kk = _mm(h, p["wk"], mode).reshape(s, Hkv, hd).transpose(1, 0, 2)
-        v = _mm(h, p["wv"], mode).reshape(s, Hkv, hd).transpose(1, 0, 2)
-        if qk_norm:
-            q = _rms(q, p["q_norm"], eps)
-            kk = _rms(kk, p["k_norm"], eps)
-        q, kk = _rope(q, pos, theta), _rope(kk, pos, theta)
-        kk, v = jnp.repeat(kk, g, axis=0), jnp.repeat(v, g, axis=0)
-        sc = _mm(q, kk.transpose(0, 2, 1), mode) * hd ** -0.5
-        sc = jnp.where(causal, sc, -jnp.inf)
-        pr = jax.nn.softmax(sc, axis=-1)
-        o = _mm(pr, v, mode).transpose(1, 0, 2).reshape(s, H * hd)
-        x = x + _mm(o, p["wo"], mode)
-        h = _rms(x, p["mlp_norm"], eps)
-        m = jax.nn.silu(_mm(h, p["w_gate"], mode)) * _mm(h, p["w_up"], mode)
-        return x + _mm(m, p["w_down"], mode), None
-
-    per_layer = {n: a for n, a in w.items()
-                 if n not in ("embed", "final_norm", "head")}
-    x, _ = jax.lax.scan(layer, x, per_layer)
-    x = _rms(x, w["final_norm"], eps)
-    head = w["embed"].T if tied else w["head"]
-    return _mm(x, head, mode)
-
-
-def _key(c: dict) -> tuple:
-    k = dims(c)
-    return (k["L"], k["d"], k["H"], k["Hkv"], k["hd"], k["qk_norm"],
-            k["tied"], float(c["rms_norm_eps"]), float(c["rope_theta"]))
-
-
 def reference_weights(c: dict, seed: int) -> dict:
     """The weights served for ``seed`` (in the configuration's dtype),
     widened to float32."""
@@ -123,7 +77,8 @@ def logits(c: dict, w: dict, tokens: np.ndarray, mode: str = "f32"):
     n = len(tokens)
     padded = np.zeros(-(-n // BUCKET) * BUCKET, np.int32)
     padded[:n] = tokens
-    out = _forward(_key(c), w, jnp.asarray(padded), mode)
+    a = work.arch(c)
+    out = a.forward(a.forward_key(c), w, jnp.asarray(padded), mode)
     return np.asarray(out[:n], np.float32)
 
 

@@ -90,3 +90,20 @@ def test_no_program_traced_inside_the_window(monkeypatch):
     monkeypatch.setattr(harness, "run_window", counted)
     _run(smoke_cell("chat"), False)
     assert counts["window"] == 0
+
+
+def test_closed_loop_warmup_crosses_pages_at_every_phase():
+    """The closed loop's set-up serves one fused run's worth of requests
+    that need their second page at every step of a run, and complete on
+    consecutive steps past the page boundary, within ``max_len``."""
+    cell = smoke_cell("pool4")
+    sv = cell.config["serving"]
+    n, page = sv["max_fused_steps"], sv["page_slots"]
+    items = harness._crossing_warmup(cell.config, cell.mix, SEED)
+    assert len(items) == n <= sv["slots"]
+    assert sorted((page - len(it.prompt)) % n for it in items) == \
+        list(range(n))
+    first = items[0].max_new
+    assert [it.max_new for it in items] == list(range(first, first + n))
+    assert all(page < len(it.prompt) + it.max_new <= sv["max_len"]
+               for it in items)
